@@ -5,8 +5,9 @@ and on the bug-injected candidate (must FAIL + localize).  Prints one TSV row
 per bug:  bug_id  type  clean_pass  detected  localized  expected  loc_ok  secs
 """
 import os
-os.environ.setdefault("XLA_FLAGS",
-                      "--xla_force_host_platform_device_count=8")
+from benchmarks.common import cpu_host_devices
+
+cpu_host_devices(os.environ, 8)
 
 import dataclasses
 import fnmatch

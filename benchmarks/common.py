@@ -42,12 +42,24 @@ def timeit(fn, *args, warmup: int = 1, iters: int = 5) -> float:
     return ts[len(ts) // 2] * 1e6
 
 
-def run_worker(module: str, *args, devices: int = 8, timeout: int = 1800
-               ) -> str:
-    """Run a benchmark worker in a subprocess with N forced host devices
-    (the main process must keep seeing 1 device)."""
+def cpu_host_devices(env, devices: int) -> None:
+    """Give a CPU run ``devices`` virtual host devices.  Only under
+    ``JAX_PLATFORMS=cpu``: on an accelerator a worker uses the real
+    devices."""
+    if env.get("JAX_PLATFORMS") == "cpu":
+        env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
+
+
+def run_worker(module: str, *args, devices: int = 8, timeout: int = 1800,
+               cpu: bool = False) -> str:
+    """Run a benchmark worker in a subprocess, the one process that touches
+    JAX (a parent holding an accelerator would lock the worker out).  Under
+    ``JAX_PLATFORMS=cpu`` — or with ``cpu=True``, for workers that simulate
+    a mesh — it gets ``devices`` forced host devices."""
     env = dict(os.environ)
-    env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
+    if cpu:
+        env["JAX_PLATFORMS"] = "cpu"
+    cpu_host_devices(env, devices)
     env["PYTHONPATH"] = "src"
     out = subprocess.run(
         [sys.executable, "-m", module, *map(str, args)],
@@ -56,3 +68,17 @@ def run_worker(module: str, *args, devices: int = 8, timeout: int = 1800
     if out.returncode != 0:
         raise RuntimeError(f"{module} failed:\n{out.stdout}\n{out.stderr}")
     return out.stdout
+
+
+def run_bench(module: str, timeout: int = 7200) -> None:
+    """Run ``benchmarks.<module>.run()`` in a child process, echo its
+    output, and collect the rows it emitted (for ``--json``)."""
+    out = run_worker(f"benchmarks.{module}", timeout=timeout, devices=1)
+    for line in out.splitlines():
+        print(line)
+        parts = line.split(",", 2)
+        if len(parts) == 3:
+            try:
+                ROWS.append((parts[0], float(parts[1]), parts[2]))
+            except ValueError:
+                pass

@@ -11,8 +11,9 @@ Reproduces (CPU-scaled) paper Fig 7 and Fig 8 on a BF16 mixed-precision GPT:
 Prints TSV: section  layer  name  value   (values normalized by bf16 eps).
 """
 import os
-os.environ.setdefault("XLA_FLAGS",
-                      "--xla_force_host_platform_device_count=8")
+from benchmarks.common import cpu_host_devices
+
+cpu_host_devices(os.environ, 8)
 
 import dataclasses
 import sys

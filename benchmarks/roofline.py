@@ -39,7 +39,7 @@ def run(out_json="roofline_report.json", multi_pod=False, archs=None,
     if shapes:
         args += ["--shapes", ",".join(shapes)]
     out = run_worker("benchmarks.roofline_worker", *args, devices=512,
-                     timeout=7200)
+                     timeout=7200, cpu=True)
     recs = []
     for ln in out.splitlines():
         if ln.startswith("{"):

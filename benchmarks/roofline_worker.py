@@ -5,8 +5,10 @@ reads cost_analysis + collective bytes, composes totals per (arch x shape),
 prints one JSON record per line.  See benchmarks.roofline for the method.
 """
 import os
-os.environ.setdefault("XLA_FLAGS",
-                      "--xla_force_host_platform_device_count=512")
+
+from benchmarks.common import cpu_host_devices
+
+cpu_host_devices(os.environ, 512)
 
 import argparse
 import dataclasses

@@ -21,8 +21,9 @@ reference.
 """
 import os
 
-os.environ.setdefault("XLA_FLAGS",
-                      "--xla_force_host_platform_device_count=8")
+from benchmarks.common import cpu_host_devices
+
+cpu_host_devices(os.environ, 8)
 
 import dataclasses
 import time

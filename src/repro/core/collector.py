@@ -39,6 +39,24 @@ def device_ctx(device):
     return jax.default_device(device) if device is not None else nullcontext()
 
 
+def full_precision():
+    """Context in which float32 matmuls run at full float32 precision.
+
+    The thresholds assume float32's epsilon, but a TPU runs float32 matmuls
+    as bfloat16 passes by default.  The precision is fixed when a function
+    is traced, so entering this around the CALL of a jitted step is enough
+    (it is part of the jit cache key)."""
+    return jax.default_matmul_precision("highest")
+
+
+def in_full_precision(fn):
+    """``fn`` called under ``full_precision()``."""
+    def wrapped(*args, **kwargs):
+        with full_precision():
+            return fn(*args, **kwargs)
+    return wrapped
+
+
 # ---------------------------------------------------------------------------
 # pytree <-> flat named dict
 # ---------------------------------------------------------------------------
@@ -262,7 +280,7 @@ def trace_fn_step(loss_call, params, batch, opt=None, opt_state=None,
             loss_fn, argnums=(0, 1), has_aux=True)(p, probes)
         return loss, fwd, pgrads, agrads
 
-    step_c = jax.jit(step) if jit else step
+    step_c = in_full_precision(jax.jit(step) if jit else step)
     loss, fwd, pgrads, agrads = step_c(params, probes)
 
     tr = Trace()
@@ -274,7 +292,7 @@ def trace_fn_step(loss_call, params, batch, opt=None, opt_state=None,
 
     new_params, new_state = params, opt_state
     if opt is not None:
-        upd = jax.jit(opt.update) if jit else opt.update
+        upd = in_full_precision(jax.jit(opt.update) if jit else opt.update)
         new_params, new_state, info = upd(params, pgrads, opt_state)
         tr.main_grads = flatten_named(info.main_grads)
         tr.params_post = flatten_named(new_params)
@@ -325,7 +343,7 @@ def make_trace_step(loss_call, opt, params, batch,
     step_c = jax.jit(_step) if jit else _step
 
     def step(p, st, b):
-        with device_ctx(device):
+        with device_ctx(device), full_precision():
             (loss, fwd, pgrads, agrads, new_p, new_st,
              main_grads, grad_norm) = step_c(p, st, b, probes)
         tr = Trace()
@@ -412,7 +430,7 @@ def make_pair_collector(loss_call, opt, params, batch, *,
     flags = jnp.asarray([0.0, 1.0], jnp.float32)
 
     def collect(p, st, batch2, step: int = 0) -> tuple[Trace, Trace]:
-        with device_ctx(device):
+        with device_ctx(device), full_precision():
             b2 = {k: jnp.asarray(v) for k, v in batch2.items()}
             loss, fwd, pg, ag, mg, new_p, gn = pair_c(p, st, b2, flags,
                                                       jnp.int32(step), probes)

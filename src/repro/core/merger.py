@@ -495,6 +495,10 @@ class MergePlan:
         records = list(records)
         if not self.matches(records):
             self.fallbacks += 1
+            import warnings
+            warnings.warn("MergePlan fallback: the record set no longer "
+                          "matches the compiled plan; running the full "
+                          "merge", RuntimeWarning)
             self.stage_param_grads = None
             return merge_microbatch_traces(records, self.tables, self.M,
                                            place=self.place)

@@ -5,9 +5,10 @@ question: for N tensor pairs of one trace section, what are the N relative
 Frobenius errors?  This module answers it in (at most) one device dispatch
 per section instead of N host-side float64 loops:
 
-* **TPU**: the pairs are packed into two block-aligned flat buffers on
+* **TPU**: the pairs are packed into block-aligned flat buffers on
   device and handed to the packed segmented Pallas kernel
-  (``repro.kernels.relerr.packed_sq_norms``) — one grid launch, N x 2
+  (``repro.kernels.relerr.packed_sq_norms``) — one program with one
+  launch per group of at most ``PACK_GROUP_ELEMS`` elements, N x 2
   scalars transferred back.
 * **CPU**: device buffers ARE host memory, so the fastest executor is f32
   BLAS over zero-copy numpy views — in-place subtract into a reused scratch
@@ -111,12 +112,43 @@ def pack_device(leaves_a, leaves_b, block: int = K.DEFAULT_BLOCK):
     return a_flat, b_flat, jnp.asarray(seg_ids), jnp.asarray(counts)
 
 
+# Largest packed copy (elements per side) one kernel launch reads.  A
+# full-width trace is GBs, and packing it whole doubled it in HBM (an
+# out-of-memory at TinyLlama's widths on one v5e chip); packed in groups
+# inside one program, the copies of one group die before the next is
+# made.  A leaf larger than this is packed alone, and a block-aligned one
+# then reaches the kernel without any copy.
+PACK_GROUP_ELEMS = 1 << 24
+
+
+def _pack_groups(sizes) -> list[tuple[int, int]]:
+    groups, lo, acc = [], 0, 0
+    for i, n in enumerate(sizes):
+        if i > lo and acc + n > PACK_GROUP_ELEMS:
+            groups.append((lo, i))
+            lo, acc = i, 0
+        acc += n
+    groups.append((lo, len(sizes)))
+    return groups
+
+
+@jax.jit
+def _packed_pairs(leaves_a, leaves_b):
+    """(N, 2) ``(||a-b||^2, ||a||^2)`` on the packed kernel: one program,
+    one kernel launch per group of pairs."""
+    from repro.kernels import ops
+    outs = []
+    for lo, hi in _pack_groups([x.size for x in leaves_a]):
+        a_flat, b_flat, seg_ids, counts = pack_device(leaves_a[lo:hi],
+                                                      leaves_b[lo:hi])
+        outs.append(ops.packed_sq_norms(a_flat, b_flat, seg_ids, counts,
+                                        n_segments=hi - lo))
+    return jnp.concatenate(outs)
+
+
 def _packed_path(leaves_a, leaves_b) -> np.ndarray:
-    from repro.kernels import ops     # honors the REPRO_PALLAS_INTERPRET
-    a_flat, b_flat, seg_ids, counts = pack_device(
-        [jnp.asarray(x) for x in leaves_a], [jnp.asarray(x) for x in leaves_b])
-    out = ops.packed_sq_norms(a_flat, b_flat, seg_ids, counts,
-                              n_segments=len(leaves_a))
+    out = _packed_pairs([jnp.asarray(x) for x in leaves_a],
+                        [jnp.asarray(x) for x in leaves_b])
     return np.asarray(out, np.float64)
 
 
@@ -208,12 +240,8 @@ def sq_norms_async(leaves_a, leaves_b):
     if not leaves_a:
         return jnp.zeros((0, 2), jnp.float32)
     if jax.default_backend() == "tpu":
-        from repro.kernels import ops
-        a_flat, b_flat, seg_ids, counts = pack_device(
-            [jnp.asarray(x) for x in leaves_a],
-            [jnp.asarray(x) for x in leaves_b])
-        return ops.packed_sq_norms(a_flat, b_flat, seg_ids, counts,
-                                   n_segments=len(leaves_a))
+        return _packed_pairs([jnp.asarray(x) for x in leaves_a],
+                             [jnp.asarray(x) for x in leaves_b])
     return _fused_pair_sq_norms([jnp.asarray(x) for x in leaves_a],
                                 [jnp.asarray(x) for x in leaves_b])
 

@@ -171,7 +171,10 @@ def estimate_thresholds(run_trace, batch: dict, eps: float,
                    for k in batch}
         try:
             t1, t2 = pair(stacked)
-        except Exception as e:      # model not vmappable -> serial fallback
+        except NotImplementedError as e:
+            # a primitive with no batching rule: the model cannot be
+            # vmapped, so the two runs go serially.  Anything else (out of
+            # memory, a compiler refusal) is a real failure and propagates
             import warnings
             warnings.warn(
                 "fused threshold estimation failed "

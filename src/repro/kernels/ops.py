@@ -1,16 +1,11 @@
 """Jitted public wrappers for the Pallas kernels.
 
-Interpret mode is auto-selected from the backend: compiled Mosaic on TPU,
-interpreter elsewhere (this container has no TPU).  Override with the
-REPRO_PALLAS_INTERPRET env var (0/1) or by setting
-``repro.kernels.ops.INTERPRET`` to True/False directly; ``INTERPRET =
-None`` means auto.  Auto-selection happens at call time, not import time —
-importing this module must not initialize the JAX backend (scripts set
-XLA_FLAGS after imports).
+Interpret mode follows the backend: compiled Mosaic on TPU, the Pallas
+interpreter on backends with no Mosaic lowering (the CPU test runs).  The
+choice is made at call time, not import time — importing this module must
+not initialize the JAX backend.
 """
 from __future__ import annotations
-
-import os
 
 import jax.numpy as jnp
 
@@ -19,23 +14,16 @@ from repro.kernels import fp8_matmul as _mm
 from repro.kernels import relerr as _re
 from repro.kernels import ssm_scan as _ssm
 
-_env = os.environ.get("REPRO_PALLAS_INTERPRET")
-INTERPRET = (_env != "0") if _env is not None else None
-
-
-def interpret_mode() -> bool:
-    return _re.default_interpret() if INTERPRET is None else INTERPRET
-
 
 def flash_attention(q, k, v, mode="causal", window=0, bq=512, bk=512):
     return _fa.flash_attention(q, k, v, mode=mode, window=window, bq=bq,
-                               bk=bk, interpret=interpret_mode())
+                               bk=bk, interpret=_re.default_interpret())
 
 
 def gla_scan(q, k, v, log_w, chunk=128, exclusive=False, u=None):
     """Kernel-backed equivalent of models.ssm.lin_attn_chunked (s0=0)."""
     y, s = _ssm.gla_scan(q, k, v, log_w, chunk=chunk, exclusive=exclusive,
-                         interpret=interpret_mode())
+                         interpret=_re.default_interpret())
     if u is not None:
         bonus = jnp.einsum("bshk,hk,bshk->bsh", q.astype(jnp.float32),
                            u.astype(jnp.float32), k.astype(jnp.float32))
@@ -45,16 +33,17 @@ def gla_scan(q, k, v, log_w, chunk=128, exclusive=False, u=None):
 
 def fp8_matmul(x, w, bm=256, bn=256, bk=256):
     return _mm.fp8_matmul(x, w, bm=bm, bn=bn, bk=bk,
-                          interpret=interpret_mode())
+                          interpret=_re.default_interpret())
 
 
 def fp8_matmul_tile128(x, sx, w, sw):
     """Per-128x128-tile-scaled fp8 matmul (compact tile scales ride along)."""
-    return _mm.fp8_matmul_tile128(x, sx, w, sw, interpret=interpret_mode())
+    return _mm.fp8_matmul_tile128(x, sx, w, sw,
+                                  interpret=_re.default_interpret())
 
 
 def rel_err(a, b) -> float:
-    return _re.rel_err_fused(a, b, interpret=interpret_mode())
+    return _re.rel_err_fused(a, b, interpret=_re.default_interpret())
 
 
 def packed_sq_norms(a_flat, b_flat, seg_ids, counts, n_segments,
@@ -62,4 +51,4 @@ def packed_sq_norms(a_flat, b_flat, seg_ids, counts, n_segments,
     """Packed segmented (||a-b||^2, ||a||^2) over N pairs in one launch."""
     return _re.packed_sq_norms(a_flat, b_flat, seg_ids, counts,
                                n_segments=n_segments, block=block,
-                               interpret=interpret_mode())
+                               interpret=_re.default_interpret())

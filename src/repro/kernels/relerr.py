@@ -37,6 +37,12 @@ from jax.experimental.pallas import tpu as pltpu
 # negligible for trace-scale tensors.
 LANES = 128
 DEFAULT_BLOCK = 1024
+# The per-block segment ids and counts are scalar-prefetched into SMEM,
+# which holds 1 MiB on v5e: a float32 parameter section of TinyLlama's
+# 2-layer cut (154M elements, 150k blocks) overflows it.  One launch covers
+# at most this many blocks (512 KiB of metadata); a longer section takes
+# several launches over the same buffers, whose (N, 2) partial sums add.
+MAX_LAUNCH_BLOCKS = 1 << 16
 
 
 def default_interpret() -> bool:
@@ -68,8 +74,7 @@ def _packed_relerr_kernel(seg_ref, cnt_ref, a_ref, b_ref, out_ref):
     a = jnp.where(valid, a, 0.0)
     seg = seg_ref[i]
     upd = jnp.stack([jnp.sum(d * d), jnp.sum(a * a)]).reshape(1, 2)
-    cur = pl.load(out_ref, (pl.ds(seg, 1), slice(None)))
-    pl.store(out_ref, (pl.ds(seg, 1), slice(None)), cur + upd)
+    out_ref[pl.ds(seg, 1), :] += upd
 
 
 @functools.partial(jax.jit,
@@ -77,8 +82,9 @@ def _packed_relerr_kernel(seg_ref, cnt_ref, a_ref, b_ref, out_ref):
 def packed_sq_norms(a_flat, b_flat, seg_ids, counts, n_segments: int,
                     block: int = DEFAULT_BLOCK,
                     interpret: bool | None = None):
-    """One grid launch over the packed section -> (n_segments, 2) f32 of
-    ``(||a-b||^2, ||a||^2)`` per pair.
+    """Grid launches over the packed section -> (n_segments, 2) f32 of
+    ``(||a-b||^2, ||a||^2)`` per pair: one launch per
+    ``MAX_LAUNCH_BLOCKS`` blocks.
 
     ``a_flat``/``b_flat``: packed flat buffers, length divisible by
     ``block``; ``seg_ids``/``counts``: int32 per-block metadata (see module
@@ -91,19 +97,31 @@ def packed_sq_norms(a_flat, b_flat, seg_ids, counts, n_segments: int,
     nb = a_flat.shape[0] // block
     a2 = a_flat.reshape(nb * rows, LANES)
     b2 = b_flat.reshape(nb * rows, LANES)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(nb,),
-        in_specs=[pl.BlockSpec((rows, LANES), lambda i, *_: (i, 0)),
-                  pl.BlockSpec((rows, LANES), lambda i, *_: (i, 0))],
-        out_specs=pl.BlockSpec((n_segments, 2), lambda i, *_: (0, 0)),
-    )
-    return pl.pallas_call(
-        _packed_relerr_kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n_segments, 2), jnp.float32),
-        interpret=interpret,
-    )(seg_ids, counts, a2, b2)
+    out = None
+    for start in range(0, nb, MAX_LAUNCH_BLOCKS):
+        n = min(MAX_LAUNCH_BLOCKS, nb - start)
+
+        def in_block(i, *_, start=start):
+            return (start + i, 0)
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(n,),
+            in_specs=[pl.BlockSpec((rows, LANES), in_block),
+                      pl.BlockSpec((rows, LANES), in_block)],
+            out_specs=pl.BlockSpec((n_segments, 2), lambda i, *_: (0, 0)),
+        )
+        part = pl.pallas_call(
+            _packed_relerr_kernel,
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct((n_segments, 2), jnp.float32),
+            # every grid step accumulates into the one resident output
+            # block, so the grid axis must run in order
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary",)),
+            interpret=interpret,
+        )(seg_ids[start:start + n], counts[start:start + n], a2, b2)
+        out = part if out is None else out + part
+    return out
 
 
 def packed_sq_norms_xla(a_flat, b_flat, seg_ids, n_segments: int,

@@ -1,4 +1,6 @@
 import os
+# a CPU simulation of the production mesh: 512 virtual host devices
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 
 """Multi-pod dry run (deliverable e).

@@ -23,13 +23,14 @@ localized.  The paper's §3 workflow (steps 1-5), looped per step.  FP8
 recipes are checked under BF16-epsilon thresholds automatically (§6.7);
 ``--reestimate-every R`` re-runs the fused threshold estimate on the live
 batch every R steps and tightens the supervised margins.
+
+The float32 recipes run both sides in float32 and every side at
+``highest`` matmul precision; the fp8 recipes keep the arch's dtypes.  The
+candidate uses the devices JAX finds: on the CPU, the multi-device recipes
+need ``XLA_FLAGS=--xla_force_host_platform_device_count=8`` (and
+``JAX_PLATFORMS=cpu``) set before the run.
 """
 from __future__ import annotations
-
-import os
-
-if "XLA_FLAGS" not in os.environ:                       # noqa: E402
-    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 
 import argparse
 import dataclasses
@@ -209,6 +210,7 @@ def main(argv=None):
 
     import jax
     from repro.bugs.registry import BUGS
+    from repro.launch.cache import enable_compile_cache
     from repro.configs.base import get_config
     from repro.models.model import Model
     from repro.optim.adamw import AdamW
@@ -228,10 +230,17 @@ def main(argv=None):
         cfg = cfg.reduced()
     if args.layers is not None:
         cfg = dataclasses.replace(cfg, n_layers=args.layers)
-    # the candidate recipes implement the GPT/Llama/MoE families
-    cfg = dataclasses.replace(cfg, tie_embeddings=True)
+    # the candidate recipes implement the GPT/Llama/MoE families with one
+    # tap scope per layer, so the layers are unrolled, never scanned
+    cfg = dataclasses.replace(cfg, tie_embeddings=True, scan_layers=False)
     recipe, pcfg = build_pcfg(args, set(spec.requires) if spec else set(),
                               arch_is_moe=cfg.arch_type == "moe")
+    if not pcfg.fp8:
+        # the float32 recipes are checked under float32 epsilon, so both
+        # sides hold and compute in float32; fp8 keeps the arch's dtypes
+        cfg = dataclasses.replace(cfg, param_dtype="float32",
+                                  compute_dtype="float32")
+    enable_compile_cache()
 
     model = Model(cfg)
     params = model.init(jax.random.PRNGKey(args.seed))

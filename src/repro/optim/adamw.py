@@ -83,7 +83,11 @@ class AdamW:
         f32 = lambda t: jax.tree.map(
             lambda x: jnp.zeros(x.shape, jnp.float32), t)
         master = jax.tree.map(lambda x: x.astype(jnp.float32), params)
-        return {"master": master, "m": f32(params), "v": f32(params),
+        # both moments start as the same zeros: arrays are immutable and no
+        # step donates them, so one buffer per leaf serves both until the
+        # first update (a model's worth of device memory at full width)
+        zeros = f32(params)
+        return {"master": master, "m": zeros, "v": zeros,
                 "step": jnp.zeros((), jnp.int32)}
 
     def _decay_mask(self, params):

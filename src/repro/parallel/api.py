@@ -27,29 +27,17 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.configs.base import ArchConfig
 from repro.core.annotations import Annotations, ShardSpec
-from repro.core.collector import Trace, flatten_named, unflatten_named
+from repro.core.collector import (Trace, flatten_named, in_full_precision,
+                                  unflatten_named)
 from repro.core.tap import TraceContext
 from repro.parallel.gpt import parallel_gpt_loss
 from repro.parallel.layers import permute_from_zigzag, permute_to_zigzag
 from repro.parallel.zero import zero1_update
-
-try:                               # jax >= 0.6: top-level, check_vma kwarg
-    from jax import shard_map as _shard_map
-    _SM_CHECK_KW = "check_vma"
-except ImportError:                # jax 0.4.x: experimental, check_rep kwarg
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _SM_CHECK_KW = "check_rep"
-
-
-def shard_map_unchecked(f, mesh, in_specs, out_specs):
-    """shard_map with replication/VMA checking off, across jax versions."""
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **{_SM_CHECK_KW: False})
-
 
 MESH_AXES = {"dp": "dp", "cp": "cp", "tp": "tp", "sp": "tp"}
 
@@ -104,16 +92,19 @@ class ParallelConfig:
         return "shard_map"
 
 
-def spare_host_device(pcfg: ParallelConfig):
-    """The last device OUTSIDE the candidate's placement footprint, or None.
+def reference_device(pcfg: ParallelConfig):
+    """The device the supervisor's reference step runs on; None with one
+    device.
 
-    Candidate recipes place on the first ``pcfg.n_devices`` devices (the
-    shard_map mesh, the 1F1B per-stage submeshes, device 0 for the
-    single-controller recipes), so the last device — when one is spare —
-    forms a disjoint set the supervisor's reference step can run on
-    concurrently."""
+    Candidate recipes place from device 0 up (the shard_map mesh, the 1F1B
+    per-stage submeshes, and device 0 for the single-controller recipes and
+    the 1F1B controller's merged trace and optimizer step), so the last
+    device is the one they load least.  When it lies outside the
+    candidate's ``pcfg.n_devices`` the two steps run concurrently; when the
+    candidate spans every device it still keeps the reference's state and
+    step off the controller."""
     devs = jax.devices()
-    return devs[-1] if len(devs) > pcfg.n_devices else None
+    return devs[-1] if len(devs) > 1 else None
 
 
 def make_device_mesh(pcfg: ParallelConfig) -> Mesh:
@@ -123,8 +114,8 @@ def make_device_mesh(pcfg: ParallelConfig) -> Mesh:
     devs = jax.devices()
     if len(devs) < n:
         raise RuntimeError(
-            f"need {n} devices, have {len(devs)} — run under "
-            f"XLA_FLAGS=--xla_force_host_platform_device_count={n}")
+            f"the dp={pcfg.dp} x cp={pcfg.cp} x tp={pcfg.tp} mesh needs {n} "
+            f"devices; found {len(devs)} {devs[0].platform} device(s)")
     arr = np.array(devs[:n]).reshape(pcfg.dp, pcfg.cp, pcfg.tp)
     return Mesh(arr, ("dp", "cp", "tp"))
 
@@ -469,10 +460,10 @@ class _Plumbing:
                 ti.update({k: (v.shape, v.dtype)
                            for k, v in ctx.fwd.items()})
                 return jnp.zeros(())
-            jax.eval_shape(shard_map_unchecked(
+            jax.eval_shape(shard_map(
                 body_d, mesh=self.mesh,
                 in_specs=(self.param_specs_tree, self.batch_spec),
-                out_specs=P()), self.params_sds, b_sds)
+                out_specs=P(), check_vma=False), self.params_sds, b_sds)
             cached = _TAP_CACHE[tap_key] = (list(ti), ti)
         names, ti = cached
         pspecs = {n: spec_to_pspec(self.ann.act_spec(n), len(ti[n][0]), pcfg)
@@ -495,35 +486,44 @@ class _Plumbing:
 
     def cached_shard_map(self, tap_key, pspecs, probe_specs, rew_specs,
                         probes, jit: bool):
-        """The compiled (or raw) shard-mapped step for one signature."""
+        """The compiled (or raw) shard-mapped step for one signature.
+
+        It takes the batch and rewrites and returns the taps in LOGICAL
+        sequence order: the zigzag (un)permutation of context parallelism
+        is part of the traced step."""
         step_key = tap_key + (tuple(probes), tuple(sorted(rew_specs)),
                               bool(jit))
         fn = _STEP_CACHE.get(step_key)
         if fn is None:
-            sm = shard_map_unchecked(
+            sm = shard_map(
                 self.body, mesh=self.mesh,
                 in_specs=(self.param_specs_tree, self.batch_spec,
                           probe_specs, rew_specs),
                 out_specs=(P(), pspecs, self.param_specs_tree,
-                           {n: pspecs[n] for n in probes}))
-            fn = _STEP_CACHE[step_key] = jax.jit(sm) if jit else sm
+                           {n: pspecs[n] for n in probes}),
+                check_vma=False)
+
+            def logical(p, b, pr, rew):
+                b = {k: permute_to_zigzag(b[k], self.pcfg.cp, 1)
+                     for k in ("tokens", "labels")}
+                rew = {n: self.zig(n, v, permute_to_zigzag)
+                       for n, v in rew.items()}
+                loss, taps, pgt, ag = sm(p, b, pr, rew)
+                taps = {n: self.zig(n, v, permute_from_zigzag)
+                        for n, v in taps.items()}
+                ag = {n: self.zig(n, v, permute_from_zigzag)
+                      for n, v in ag.items()}
+                return loss, taps, pgt, ag
+            fn = _STEP_CACHE[step_key] = jax.jit(logical) if jit else logical
         return fn
 
-    def unzig(self, n, x):
+    def zig(self, n, x, permute):
+        """``permute`` tap ``n`` along its context-parallel dim (identity
+        when cp == 1 or the tap has none)."""
         spec = self.ann.act_spec(n)
         if self.pcfg.cp > 1 and spec.cp_dim is not None:
-            return permute_from_zigzag(x, self.pcfg.cp,
-                                       spec.cp_dim % x.ndim)
+            return permute(x, self.pcfg.cp, spec.cp_dim % x.ndim)
         return x
-
-    def zigzag_batch(self, batch: dict) -> dict:
-        out = {}
-        for k in ("tokens", "labels"):
-            v = jnp.asarray(batch[k])
-            if self.pcfg.cp > 1:
-                v = permute_to_zigzag(v, self.pcfg.cp, 1)
-            out[k] = v
-        return out
 
 
 def make_candidate_runner(cfg: ArchConfig, pcfg: ParallelConfig,
@@ -533,7 +533,8 @@ def make_candidate_runner(cfg: ArchConfig, pcfg: ParallelConfig,
     the shard_map distributed GPT, or (dispatching on ``pcfg``) the staged
     pipeline / FP8 candidates."""
     if pcfg.recipe_kind != "shard_map":
-        return _recipe_runner(cfg, pcfg, ref_params, opt, opt_state)
+        return in_full_precision(
+            _recipe_runner(cfg, pcfg, ref_params, opt, opt_state))
     pl = _Plumbing(cfg, pcfg, ref_params)
     bugs = pcfg.bugs
 
@@ -545,24 +546,16 @@ def make_candidate_runner(cfg: ArchConfig, pcfg: ParallelConfig,
     params = unflatten_named(sharded, ref_params)
 
     def prep_batch(batch):
-        return {k: jax.device_put(v, NamedSharding(pl.mesh,
-                                                   pl.batch_spec[k]))
-                for k, v in pl.zigzag_batch(batch).items()}
+        return {k: jax.device_put(jnp.asarray(batch[k]),
+                                  NamedSharding(pl.mesh, pl.batch_spec[k]))
+                for k in ("tokens", "labels")}
 
     def _run(batch, rewrites=None) -> Trace:
         b = prep_batch(batch)
         tap_key, names, ti, pspecs, probes, probe_specs = pl.taps_for(b)
-        rew_in = {}
-        if rewrites:
-            for n, v in rewrites.items():
-                if n not in names:
-                    continue
-                v = jnp.asarray(v)
-                spec = pl.ann.act_spec(n)
-                if pcfg.cp > 1 and spec.cp_dim is not None:
-                    v = permute_to_zigzag(v, pcfg.cp, spec.cp_dim % v.ndim)
-                rew_in[n] = jax.device_put(
-                    v, NamedSharding(pl.mesh, pspecs[n]))
+        rew_in = {n: jax.device_put(jnp.asarray(v),
+                                    NamedSharding(pl.mesh, pspecs[n]))
+                  for n, v in (rewrites or {}).items() if n in names}
         rew_specs = {n: pspecs[n] for n in rew_in}
 
         fn = pl.cached_shard_map(tap_key, pspecs, probe_specs, rew_specs,
@@ -573,8 +566,8 @@ def make_candidate_runner(cfg: ArchConfig, pcfg: ParallelConfig,
         tr.loss = float(loss)
         # leaves stay device-resident jax.Arrays — the batched checker reads
         # them in place and only reduction scalars reach the host
-        tr.activations = {n: pl.unzig(n, taps[n]) for n in names}
-        tr.act_grads = {n: pl.unzig(n, ag[n]) for n in names if n in ag}
+        tr.activations = {n: taps[n] for n in names}
+        tr.act_grads = {n: ag[n] for n in names if n in ag}
         pg_named = {k: pl.from_cand(k, v)
                     for k, v in flatten_named(pgt).items()}
         tr.param_grads = dict(pg_named)
@@ -596,7 +589,7 @@ def make_candidate_runner(cfg: ArchConfig, pcfg: ParallelConfig,
             tr.grad_norm = float(info.grad_norm)
         return tr
 
-    return _run
+    return in_full_precision(_run)
 
 
 # ---------------------------------------------------------------------------
@@ -627,7 +620,9 @@ def make_candidate_train_step(cfg: ArchConfig, pcfg: ParallelConfig,
     (``parallel.pp`` / ``precision.fp8``).
     """
     if pcfg.recipe_kind != "shard_map":
-        return _recipe_train_step(cfg, pcfg, ref_params, opt, batch)
+        step, params0, state0 = _recipe_train_step(cfg, pcfg, ref_params,
+                                                   opt, batch)
+        return in_full_precision(step), params0, state0
     pl = _Plumbing(cfg, pcfg, ref_params)
     bugs = pcfg.bugs
     tap_key, names, ti, pspecs, probes, probe_specs = pl.taps_for(
@@ -654,17 +649,11 @@ def make_candidate_train_step(cfg: ArchConfig, pcfg: ParallelConfig,
 
     step_c = jax.jit(_step)
 
+    @in_full_precision
     def step(params, opt_state, batch) -> tuple[Trace, dict, dict]:
-        # zigzag (un)permutation stays EAGER on both sides of the jitted
-        # step: global split/concat/reshape of sharded leaves inside jit
-        # miscompiles under GSPMD on this jax line (see zero1_update), and
-        # the eager path is the one the one-shot runner already proves out.
-        # cp == 1 makes both transforms the identity.
-        bb = pl.zigzag_batch(batch)
+        bb = {k: batch[k] for k in ("tokens", "labels")}
         (loss, taps, pg_named, ag, main_grads, grad_norm,
          new_p, new_st) = step_c(params, opt_state, bb, probes)
-        taps = {n: pl.unzig(n, taps[n]) for n in taps}
-        ag = {n: pl.unzig(n, ag[n]) for n in ag}
         tr = Trace()
         tr.loss = loss
         tr.grad_norm = grad_norm
@@ -731,26 +720,23 @@ def make_plain_train_step(cfg: ArchConfig, pcfg: ParallelConfig,
             rloss = jax.lax.psum(rloss, loss_axes) / (pcfg.dp * pcfg.cp)
         return rloss, unflatten_named(pg, grads)
 
-    sm = shard_map_unchecked(body, mesh=mesh,
-                             in_specs=(spec_tree, {"tokens": bspec,
-                                                   "labels": bspec}),
-                             out_specs=(P(), spec_tree))
+    sm = shard_map(body, mesh=mesh,
+                   in_specs=(spec_tree, {"tokens": bspec, "labels": bspec}),
+                   out_specs=(P(), spec_tree), check_vma=False)
 
     opt_state = opt.init(params)
 
     @jax.jit
     def step(params, opt_state, batch):
+        batch = {k: permute_to_zigzag(v, pcfg.cp, 1)
+                 for k, v in batch.items()}
         loss, grads = sm(params, batch)
         params, opt_state, info = opt.update(params, grads, opt_state)
         return params, opt_state, loss
 
     def prep(batch):
-        out = {}
-        for k in ("tokens", "labels"):
-            v = jnp.asarray(batch[k])
-            if pcfg.cp > 1:
-                v = permute_to_zigzag(v, pcfg.cp, 1)
-            out[k] = jax.device_put(v, NamedSharding(mesh, bspec))
-        return out
+        return {k: jax.device_put(jnp.asarray(batch[k]),
+                                  NamedSharding(mesh, bspec))
+                for k in ("tokens", "labels")}
 
     return step, prep, params, opt_state

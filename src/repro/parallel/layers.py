@@ -13,6 +13,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core.tap import ensure_ctx
 from repro.models.attention import NEG_INF, attention_ref
@@ -22,11 +23,9 @@ AX_DP, AX_CP, AX_TP = "dp", "cp", "tp"
 
 
 def axis_size(name):
-    # jax.lax.axis_size only exists on newer jax; psum of the python scalar
-    # 1 is the version-stable spelling — it folds to the static axis size
-    # without tracing
+    """Static size of a mesh axis; 1 outside a shard_map that binds it."""
     try:
-        return jax.lax.psum(1, name)
+        return jax.lax.axis_size(name)
     except NameError:
         return 1
 
@@ -111,21 +110,25 @@ def zigzag_order(cp: int) -> list[int]:
     return out
 
 
+def zigzag_index(seq: int, cp: int) -> np.ndarray:
+    """Logical sequence positions in zigzag layout order: contiguous rank
+    splits of the permuted axis give rank r the chunks (r, 2cp-1-r)."""
+    chunks = np.arange(seq).reshape(2 * cp, -1)
+    return np.concatenate([chunks[c] for c in zigzag_order(cp)])
+
+
 def permute_to_zigzag(x, cp: int, dim: int):
+    """Logical -> zigzag order along ``dim``: one gather with a static
+    index, so it traces inside jit on sharded inputs."""
     if cp == 1:
         return x
-    order = zigzag_order(cp)
-    chunks = jnp.split(x, 2 * cp, axis=dim)
-    return jnp.concatenate([chunks[c] for c in order], axis=dim)
+    return jnp.take(x, zigzag_index(x.shape[dim], cp), axis=dim)
 
 
 def permute_from_zigzag(x, cp: int, dim: int):
     if cp == 1:
         return x
-    order = zigzag_order(cp)
-    inv = [order.index(i) for i in range(2 * cp)]
-    chunks = jnp.split(x, 2 * cp, axis=dim)
-    return jnp.concatenate([chunks[c] for c in inv], axis=dim)
+    return jnp.take(x, np.argsort(zigzag_index(x.shape[dim], cp)), axis=dim)
 
 
 def local_positions(seq_global: int, cp: int):

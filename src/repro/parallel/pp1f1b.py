@@ -245,9 +245,8 @@ class PP1F1BEngine:
         devs = jax.devices()
         if len(devs) < pp_size:
             raise RuntimeError(
-                f"need {pp_size} devices for {pp_size} pipeline stages, "
-                f"have {len(devs)} — run under "
-                f"XLA_FLAGS=--xla_force_host_platform_device_count={pp_size}")
+                f"{pp_size} pipeline stages need {pp_size} devices; found "
+                f"{len(devs)} {devs[0].platform} device(s)")
         self.model, self.cfg = model, cfg
         self.bugs = frozenset(bugs)
         self.pp, self.M = pp_size, n_microbatches

@@ -337,11 +337,11 @@ class Supervisor:
 
     # ---- build (thresholds + compiled steps) -------------------------------
     def _ref_device(self):
-        """The spare device the reference step (and the live threshold
+        """The device the reference step (and the live threshold
         estimator) runs on — the device partition of the overlapped loop.
-        None (shared placement) when nothing is spare or overlap is off."""
-        from repro.parallel.api import spare_host_device
-        return spare_host_device(self.pcfg) if self.scfg.overlap else None
+        None (shared placement) with one device or with overlap off."""
+        from repro.parallel.api import reference_device
+        return reference_device(self.pcfg) if self.scfg.overlap else None
 
     def _build(self):
         sc = self.scfg

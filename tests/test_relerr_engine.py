@@ -18,6 +18,7 @@ from repro.core.collector import Section, Trace, trace_pair_step, \
 from repro.core.relerr_engine import (batched_rel_err, pack_device,
                                       rel_err_np, section_sq_norms)
 from repro.core.thresholds import Thresholds
+from repro.kernels import relerr as relerr_kernels
 from repro.kernels.relerr import DEFAULT_BLOCK, packed_sq_norms, \
     packed_sq_norms_xla, sq_norms
 
@@ -65,12 +66,20 @@ def test_packed_kernel_ragged_sizes_property(seed, dtype):
     np.testing.assert_allclose(got, want, rtol=tol, atol=1e-12)
 
 
-def test_packed_kernel_matches_xla_oracle():
+@pytest.mark.parametrize("launch_blocks", [None, 2])
+def test_packed_kernel_matches_xla_oracle(monkeypatch, launch_blocks):
+    """Also when the section spans several launches (the SMEM bound on
+    the scalar-prefetched metadata): pairs then straddle launches."""
+    if launch_blocks is not None:
+        monkeypatch.setattr(relerr_kernels, "MAX_LAUNCH_BLOCKS",
+                            launch_blocks)
+    packed_sq_norms.clear_cache()
     sizes = [7, BLOCK, 2 * BLOCK + 3]
     pairs = _pairs(sizes, seed=3)
     af, bf, seg, cnt = pack_device([jnp.asarray(a) for a, _ in pairs],
                                    [jnp.asarray(b) for _, b in pairs])
     kern = np.asarray(packed_sq_norms(af, bf, seg, cnt, n_segments=3))
+    packed_sq_norms.clear_cache()
     orac = np.asarray(packed_sq_norms_xla(af, bf, seg, n_segments=3))
     np.testing.assert_allclose(kern, orac, rtol=1e-6)
 
@@ -98,6 +107,23 @@ def test_packed_kernel_zero_reference_and_empty():
     out = np.asarray(packed_sq_norms(af, bf, seg, cnt, n_segments=2))
     np.testing.assert_allclose(out[0], [16.0, 0.0], rtol=1e-6)
     np.testing.assert_allclose(out[1], [0.0, 0.0])
+
+
+@pytest.mark.parametrize("group_elems", [None, BLOCK])
+def test_packed_engine_groups_match_loop(monkeypatch, group_elems):
+    """The engine's packed path packs pairs in bounded groups (one kernel
+    launch each) inside one program; any grouping gives the loop's sums."""
+    from repro.core import relerr_engine
+    if group_elems is not None:
+        monkeypatch.setattr(relerr_engine, "PACK_GROUP_ELEMS", group_elems)
+    relerr_engine._packed_pairs.clear_cache()
+    pairs = _pairs([5, BLOCK, 3 * BLOCK + 1, 17, 2 * BLOCK], seed=11)
+    la = [jnp.asarray(a) for a, _ in pairs]
+    lb = [jnp.asarray(b) for _, b in pairs]
+    got = section_sq_norms(la, lb, mode="packed")
+    relerr_engine._packed_pairs.clear_cache()
+    np.testing.assert_allclose(got, section_sq_norms(la, lb, mode="loop"),
+                               rtol=1e-5)
 
 
 def test_single_pair_sq_norms_wrapper():
