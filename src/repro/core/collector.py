@@ -196,6 +196,13 @@ class Trace:
                 C.KIND_MAIN_GRAD: self.main_grads,
                 C.KIND_PARAM_POST: self.params_post}[kind]
 
+    @property
+    def nbytes(self) -> int:
+        """Bytes of every section's leaves, from their shapes alone (no
+        transfer, no device sync)."""
+        return sum(int(x.nbytes) for f in _SECTION_FIELDS
+                   for _, x in getattr(self, f).raw_items())
+
     def host(self) -> "Trace":
         """Force every section to host numpy (explicit bulk transfer)."""
         for f in _SECTION_FIELDS:
@@ -275,12 +282,13 @@ def trace_fn_step(loss_call, params, batch, opt=None, opt_state=None,
         loss = loss_call(p, batch, ctx)
         return loss, ctx.fwd
 
-    def step(p, probes):
+    def threshold_trace(p, probes):
         (loss, fwd), (pgrads, agrads) = jax.value_and_grad(
             loss_fn, argnums=(0, 1), has_aux=True)(p, probes)
         return loss, fwd, pgrads, agrads
 
-    step_c = in_full_precision(jax.jit(step) if jit else step)
+    step_c = in_full_precision(jax.jit(threshold_trace) if jit
+                               else threshold_trace)
     loss, fwd, pgrads, agrads = step_c(params, probes)
 
     tr = Trace()
@@ -306,7 +314,7 @@ def trace_fn_step(loss_call, params, batch, opt=None, opt_state=None,
 
 def make_trace_step(loss_call, opt, params, batch,
                     collect_act_grads: bool = True, tap_filter=None,
-                    jit: bool = True, device=None):
+                    jit: bool = True, device=None, name: str = "ref_step"):
     """Build a trace-collecting FULL train step compiled exactly once.
 
     ``trace_train_step`` re-traces every call (fresh closures -> fresh jit
@@ -324,6 +332,10 @@ def make_trace_step(loss_call, opt, params, batch,
     device as an UNCOMMITTED default — the supervisor's disjoint
     reference-device set, so reference and candidate steps dispatched
     back-to-back run concurrently.
+
+    ``name`` names the compiled program (``jit_<name>``): the reference's
+    step is ``ref_step``; a candidate recipe built on this step passes
+    ``cand_step``.
     """
     shapes, fwd_order = tap_shapes(loss_call, params, batch, None)
     with device_ctx(device):
@@ -340,6 +352,7 @@ def make_trace_step(loss_call, opt, params, batch,
         return (loss, fwd, pgrads, agrads, new_p, new_st,
                 info.main_grads, info.grad_norm)
 
+    _step.__name__ = _step.__qualname__ = name
     step_c = jax.jit(_step) if jit else _step
 
     def step(p, st, b):
@@ -417,7 +430,7 @@ def make_pair_collector(loss_call, opt, params, batch, *,
             loss_fn, argnums=(0, 1), has_aux=True)(p, pr)
         return loss, fwd, pg, ag
 
-    def _pair(p, st, b2, flags, step_k, pr):
+    def threshold_pair(p, st, b2, flags, step_k, pr):
         loss, fwd, pg, ag = jax.vmap(
             one, in_axes=(None, 0, 0, None, None))(p, b2, flags, step_k, pr)
         if opt is None:
@@ -426,7 +439,7 @@ def make_pair_collector(loss_call, opt, params, batch, *,
             opt.update, in_axes=(None, 0, None))(p, pg, st)
         return loss, fwd, pg, ag, info.main_grads, new_p, info.grad_norm
 
-    pair_c = jax.jit(_pair) if jit else _pair
+    pair_c = jax.jit(threshold_pair) if jit else threshold_pair
     flags = jnp.asarray([0.0, 1.0], jnp.float32)
 
     def collect(p, st, batch2, step: int = 0) -> tuple[Trace, Trace]:

@@ -72,7 +72,7 @@ def rel_err_np(a, b) -> float:
 # ---------------------------------------------------------------------------
 
 @jax.jit
-def _fused_pair_sq_norms(leaves_a, leaves_b):
+def relerr_fused(leaves_a, leaves_b):
     """One compiled call over all pairs: [(||a-b||^2, ||a||^2)] -> (N, 2).
 
     Retraces per section signature (pytree of shapes/dtypes); the jit cache
@@ -133,7 +133,7 @@ def _pack_groups(sizes) -> list[tuple[int, int]]:
 
 
 @jax.jit
-def _packed_pairs(leaves_a, leaves_b):
+def relerr_packed(leaves_a, leaves_b):
     """(N, 2) ``(||a-b||^2, ||a||^2)`` on the packed kernel: one program,
     one kernel launch per group of pairs."""
     from repro.kernels import ops
@@ -147,14 +147,14 @@ def _packed_pairs(leaves_a, leaves_b):
 
 
 def _packed_path(leaves_a, leaves_b) -> np.ndarray:
-    out = _packed_pairs([jnp.asarray(x) for x in leaves_a],
+    out = relerr_packed([jnp.asarray(x) for x in leaves_a],
                         [jnp.asarray(x) for x in leaves_b])
     return np.asarray(out, np.float64)
 
 
 def _fused_path(leaves_a, leaves_b) -> np.ndarray:
-    out = _fused_pair_sq_norms([jnp.asarray(x) for x in leaves_a],
-                               [jnp.asarray(x) for x in leaves_b])
+    out = relerr_fused([jnp.asarray(x) for x in leaves_a],
+                       [jnp.asarray(x) for x in leaves_b])
     return np.asarray(out, np.float64)
 
 
@@ -240,10 +240,10 @@ def sq_norms_async(leaves_a, leaves_b):
     if not leaves_a:
         return jnp.zeros((0, 2), jnp.float32)
     if jax.default_backend() == "tpu":
-        return _packed_pairs([jnp.asarray(x) for x in leaves_a],
+        return relerr_packed([jnp.asarray(x) for x in leaves_a],
                              [jnp.asarray(x) for x in leaves_b])
-    return _fused_pair_sq_norms([jnp.asarray(x) for x in leaves_a],
-                                [jnp.asarray(x) for x in leaves_b])
+    return relerr_fused([jnp.asarray(x) for x in leaves_a],
+                        [jnp.asarray(x) for x in leaves_b])
 
 
 def _to_rel_err(sq: np.ndarray) -> np.ndarray:

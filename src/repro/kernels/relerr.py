@@ -119,6 +119,7 @@ def packed_sq_norms(a_flat, b_flat, seg_ids, counts, n_segments: int,
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("arbitrary",)),
             interpret=interpret,
+            name="relerr_kernel",
         )(seg_ids[start:start + n], counts[start:start + n], a2, b2)
         out = part if out is None else out + part
     return out

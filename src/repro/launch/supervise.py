@@ -209,6 +209,7 @@ def main(argv=None):
                          "checkpoints of the interrupted run)")
 
     import jax
+    from repro import obs
     from repro.bugs.registry import BUGS
     from repro.launch.cache import enable_compile_cache
     from repro.configs.base import get_config
@@ -278,10 +279,13 @@ def main(argv=None):
     print(res.summary())
     print(f"  recipe={sup.candidate.name} eps={sup.eps:.2e}, "
           f"checked {len(res.checks)} steps, "
-          f"{res.timings.get('steps_per_s', 0):.2f} supervised steps/s "
+          f"{res.timings.get('steady_steps_per_s', 0):.2f} supervised "
+          f"steps/s after the first two "
           f"(pipeline peak in-flight {sup.pipe.max_in_flight}, "
           f"ring: {len(sup.ring.in_memory)} in mem / "
           f"{len(sup.ring.on_disk)} spilled, pinned {sorted(sup.ring.pinned)})")
+    print("  spans and counters of the run (repro.obs):")
+    print(obs.report(res.obs))
     if spec and res.flagged:
         loc = res.localized_module or "-"
         # "loss" marks bugs with no module to blame (loss-scaling family);

@@ -132,8 +132,11 @@ def block_apply(p, cfg: ArchConfig, kind: str, x, ctx, cache=None, pos=None,
     ctx = ensure_ctx(ctx)
     aux = jnp.zeros((), jnp.float32)
     if kind in ("attn_mlp", "attn_dense_mlp", "attn_moe", "shared_attn"):
-        h = rmsnorm(p["input_norm"], x)
-        with ctx.scope("self_attention"):
+        # named scopes (attn, mlp, norm) only label the compiled program's
+        # operations, so a profile says which block each one belongs to
+        with jax.named_scope("norm"):
+            h = rmsnorm(p["input_norm"], x)
+        with ctx.scope("self_attention"), jax.named_scope("attn"):
             if decode:
                 if cfg.attn == "mla":
                     a, cache = attn_mod.mla_decode(p["self_attention"], cfg, h,
@@ -149,8 +152,9 @@ def block_apply(p, cfg: ArchConfig, kind: str, x, ctx, cache=None, pos=None,
                     a = attn_mod.gqa_forward(p["self_attention"], cfg, h,
                                              ctx=ctx, use_kernel=use_kernel)
         x = x + a
-        h = rmsnorm(p["post_attn_norm"], x)
-        with ctx.scope("mlp"):
+        with jax.named_scope("norm"):
+            h = rmsnorm(p["post_attn_norm"], x)
+        with ctx.scope("mlp"), jax.named_scope("mlp"):
             if kind == "attn_moe":
                 mo, aux = moe_mod.moe_forward(p["mlp"], cfg, h, ctx=ctx)
             elif cfg.arch_type == "audio":
@@ -250,7 +254,7 @@ class Model:
     def embed(self, params, batch, ctx=None):
         cfg = self.cfg
         ctx = ensure_ctx(ctx)
-        with ctx.scope("embedding"):
+        with ctx.scope("embedding"), jax.named_scope("embed"):
             if cfg.arch_type == "audio":
                 feats = batch["features"].astype(self.cdtype)
                 h = linear(params["audio_proj"], feats)
@@ -323,7 +327,8 @@ class Model:
                 (h, aux_total), ncs = jax.lax.scan(
                     fn, (h, aux_total), (p_seg, cache))
                 new_caches[seg.name] = ncs
-        h = rmsnorm(params["final_norm"], h)
+        with jax.named_scope("norm"):
+            h = rmsnorm(params["final_norm"], h)
         h = ctx.tap("final_norm_out", h) if ctx.mode != "off" else h
         return h, aux_total, new_caches
 
@@ -348,11 +353,12 @@ class Model:
         if cfg.arch_type == "audio":
             mask = batch["mask"]
         big = h.shape[1] * cfg.vocab > (1 << 26) and not COST_MODE
-        if big:
-            ce = chunked_cross_entropy(h, e, labels, mask=mask,
-                                       chunk=min(1024, h.shape[1]))
-        else:
-            ce = cross_entropy(_logits(h, e), labels, mask=mask)
+        with jax.named_scope("loss"):
+            if big:
+                ce = chunked_cross_entropy(h, e, labels, mask=mask,
+                                           chunk=min(1024, h.shape[1]))
+            else:
+                ce = cross_entropy(_logits(h, e), labels, mask=mask)
         loss = ce + aux
         return loss, {"ce": ce, "aux": aux}
 

@@ -103,6 +103,11 @@ class AdamW:
             jax.tree_util.tree_structure(params), flat)
 
     def update(self, params, grads, state, loss_scale=None):
+        # the named scope labels the update's operations in a profile
+        with jax.named_scope("optimizer"):
+            return self._update(params, grads, state, loss_scale)
+
+    def _update(self, params, grads, state, loss_scale):
         step = state["step"] + 1
         lr = self.lr(state["step"]) if callable(self.lr) else jnp.float32(self.lr)
         grads = _reshard_like_opt_state(grads)

@@ -631,7 +631,7 @@ def make_candidate_train_step(cfg: ArchConfig, pcfg: ParallelConfig,
     sm = pl.cached_shard_map(tap_key, pspecs, probe_specs, {}, probes,
                              jit=False)
 
-    def _step(params, opt_state, b, pr):
+    def cand_step(params, opt_state, b, pr):
         cand = unflatten_named(
             {n: pl.to_cand(n, l) for n, l in flatten_named(params).items()},
             params)
@@ -647,7 +647,7 @@ def make_candidate_train_step(cfg: ArchConfig, pcfg: ParallelConfig,
         return (loss, taps, pg_named, ag, flatten_named(info.main_grads),
                 info.grad_norm, new_p, new_st)
 
-    step_c = jax.jit(_step)
+    step_c = jax.jit(cand_step)
 
     @in_full_precision
     def step(params, opt_state, batch) -> tuple[Trace, dict, dict]:

@@ -112,14 +112,18 @@ def parallel_block(p, cfg: ArchConfig, x, q_pos, li: int, sp: bool,
                    moe: bool, bugs, ctx):
     ctx = ensure_ctx(ctx)
     with ctx.scope(f"layers.{li}"):
-        h = rmsnorm(p["input_norm"], x)
-        with ctx.scope("self_attention"):
+        # named scopes label the compiled program's operations by block,
+        # as in the reference model
+        with jax.named_scope("norm"):
+            h = rmsnorm(p["input_norm"], x)
+        with ctx.scope("self_attention"), jax.named_scope("attn"):
             a = tp_gqa_attention(p["self_attention"], cfg, h, q_pos, sp,
                                  bugs=bugs, ctx=ctx)
         x = x + a
-        h = rmsnorm(p["post_attn_norm"], x)
+        with jax.named_scope("norm"):
+            h = rmsnorm(p["post_attn_norm"], x)
         stats = None
-        with ctx.scope("mlp"):
+        with ctx.scope("mlp"), jax.named_scope("mlp"):
             if moe:
                 mo, stats = tp_moe(p["mlp"], cfg, h, sp, bugs=bugs, ctx=ctx)
             else:
@@ -142,7 +146,7 @@ def parallel_gpt_loss(params, batch, cfg: ArchConfig, sp: bool,
     S_global = S_local * cp
     q_pos = local_positions(S_global, cp)
 
-    with ctx.scope("embedding"):
+    with ctx.scope("embedding"), jax.named_scope("embed"):
         h = vocab_parallel_embedding(
             params["embedding"]["word_embeddings"], tokens, cfg.vocab,
             bugs=bugs, reduce="scatter" if sp else "psum")
@@ -156,7 +160,8 @@ def parallel_gpt_loss(params, batch, cfg: ArchConfig, sp: bool,
         if stats is not None:
             all_stats.append(stats)
 
-    h = rmsnorm(params["final_norm"], h)
+    with jax.named_scope("norm"):
+        h = rmsnorm(params["final_norm"], h)
     h = ctx.tap("final_norm_out", h)
     if sp:
         h = sp_gather(h)
@@ -164,9 +169,10 @@ def parallel_gpt_loss(params, batch, cfg: ArchConfig, sp: bool,
         h = g_copy(h)
     e = (params["embedding"]["word_embeddings"] if cfg.tie_embeddings
          else params["lm_head"])
-    logits_local = h @ e.T.astype(h.dtype)            # (B, S_loc, V/tp)
-    nll = vocab_parallel_ce(logits_local, labels, cfg.vocab)
-    ce = jnp.mean(nll)
+    with jax.named_scope("loss"):
+        logits_local = h @ e.T.astype(h.dtype)        # (B, S_loc, V/tp)
+        nll = vocab_parallel_ce(logits_local, labels, cfg.vocab)
+        ce = jnp.mean(nll)
 
     # router load-balance aux loss from GLOBAL statistics: stats are summed
     # across dp/cp with a conjugate reduce so each rank's backward receives

@@ -144,6 +144,7 @@ def make_pp_train_step(model: Model, ref_params, opt, batch, pp_size: int,
     every supervised step and bisection replay."""
     import jax
     loss_call = _pp_loss_call(model, pp_size, bugs)
-    step = make_trace_step(loss_call, opt, ref_params, batch)
+    step = make_trace_step(loss_call, opt, ref_params, batch,
+                           name="cand_step")
     params0 = jax.tree.map(jnp.asarray, ref_params)
     return step, params0, opt.init(params0)

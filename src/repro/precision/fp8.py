@@ -197,6 +197,7 @@ def make_fp8_train_step(model, ref_params, opt, batch, recipe: str,
     BF16-epsilon thresholds (paper §6.7)."""
     from repro.core.collector import make_trace_step
     loss_call = _fp8_loss_call(model, fp8_precision(recipe, bugs, use_kernel))
-    step = make_trace_step(loss_call, opt, ref_params, batch)
+    step = make_trace_step(loss_call, opt, ref_params, batch,
+                           name="cand_step")
     params0 = jax.tree.map(jnp.asarray, ref_params)
     return step, params0, opt.init(params0)

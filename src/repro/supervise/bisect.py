@@ -42,6 +42,9 @@ import threading
 from dataclasses import dataclass, field
 from typing import Callable, Optional
 
+import jax
+
+from repro import obs
 from repro.checkpoint.store import (MANIFEST, ChecksumError, load_checkpoint,
                                     load_checkpoint_named, save_checkpoint)
 from repro.supervise.pipeline import StepCheck
@@ -101,11 +104,15 @@ class CheckpointKeeper:
             self._write(step, ref_state, cand_state)
 
     def _write(self, step: int, ref_state, cand_state) -> None:
-        save_checkpoint(self._dir(step),
-                        {"ref": {"params": ref_state[0], "opt": ref_state[1]},
-                         "cand": {"params": cand_state[0],
-                                  "opt": cand_state[1]}},
-                        step=step)
+        with obs.span("ckpt.write", step=step):
+            save_checkpoint(self._dir(step),
+                            {"ref": {"params": ref_state[0],
+                                     "opt": ref_state[1]},
+                             "cand": {"params": cand_state[0],
+                                      "opt": cand_state[1]}},
+                            step=step)
+        obs.count("ckpt.bytes", sum(int(x.nbytes) for x in jax.tree.leaves(
+            (ref_state, cand_state))))
         with self._lock:
             if step not in self.steps:
                 self.steps.append(step)

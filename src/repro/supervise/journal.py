@@ -38,6 +38,7 @@ import threading
 import zlib
 from typing import Any, Optional
 
+from repro import obs
 from repro.core.checker import CheckRecord, Report
 from repro.core.thresholds import Thresholds
 
@@ -89,6 +90,7 @@ class Journal:
                 return
             self._q.put({"t": etype, **fields})
             self.appended += 1
+        obs.high("writer.queue_depth.journal-writer", self._q.qsize())
 
     @staticmethod
     def _encode(rec: dict) -> str:
@@ -114,13 +116,16 @@ class Journal:
             if closing:
                 batch.pop()
             try:
-                self._f.writelines(self._encode(r) for r in batch)
-                self._f.flush()
-                if self.fsync:
-                    os.fsync(self._f.fileno())
-                    self.syncs += 1
+                with obs.span("journal.commit"):
+                    self._f.writelines(self._encode(r) for r in batch)
+                    self._f.flush()
+                    if self.fsync:
+                        os.fsync(self._f.fileno())
+                        self.syncs += 1
             except (OSError, ValueError):
                 return             # file gone under us: teardown race
+            obs.count("journal.records", len(batch))
+            obs.count("journal.fsyncs", int(self.fsync))
             if closing:
                 break
 

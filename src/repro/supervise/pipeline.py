@@ -50,6 +50,7 @@ from typing import Any, Callable, Optional
 
 import numpy as np
 
+from repro import obs
 from repro.core import canonical as C
 from repro.core.checker import (DEFAULT_KINDS, Report, collect_section_pairs,
                                 merge_problems_of, report_from_errs)
@@ -75,6 +76,11 @@ REESTIMATED_KIND_MULT = {
     C.KIND_MAIN_GRAD: 8.0,
     C.KIND_PARAM_POST: 1.0,
 }
+
+
+def _count_checked(la, lb) -> None:
+    obs.count("check.bytes", sum(int(x.nbytes) for x in la)
+              + sum(int(x.nbytes) for x in lb))
 
 
 @dataclass
@@ -240,6 +246,7 @@ class AsyncCheckPipeline:
         backpressure bound forced to resolve (oldest first)."""
         entries, la, lb, missing = collect_section_pairs(ref, cand,
                                                          self.kinds)
+        _count_checked(la, lb)
         dev = sq_norms_async(la, lb)
         if self.tap_future is not None:
             dev = self.tap_future(step, dev)
@@ -292,6 +299,7 @@ class AsyncCheckPipeline:
         (the bisection replay path, and the ``--async-window 0`` mode)."""
         entries, la, lb, missing = collect_section_pairs(ref, cand,
                                                          self.kinds)
+        _count_checked(la, lb)
         errs = _to_rel_err(np.asarray(sq_norms_async(la, lb), np.float64))
         rep = report_from_errs(entries, errs, self.thresholds_for(step),
                                missing=missing, thr_scale=self.scales(step),

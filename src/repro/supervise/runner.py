@@ -41,10 +41,10 @@ from __future__ import annotations
 
 import os
 import tempfile
-import time
 from dataclasses import dataclass, field
 from typing import Any, Callable, Optional
 
+from repro import obs
 from repro.checkpoint.store import ChecksumError
 from repro.core import canonical as C
 from repro.core.checker import Report, localize_with_rewrites
@@ -98,8 +98,9 @@ class CandidateStep:
         """Dispatch on ``pcfg`` (shard_map / pp / 1F1B / fp8) via
         ``parallel.api``."""
         import math
-        step, p0, s0 = make_candidate_train_step(cfg, pcfg, params, opt,
-                                                 batch)
+        with obs.span("candidate.build"):
+            step, p0, s0 = make_candidate_train_step(cfg, pcfg, params, opt,
+                                                     batch)
         eps = (MACHINE_EPS["float8_e4m3fn"] if pcfg.fp8
                else MACHINE_EPS["float32"])
         kind_scale = 1.0
@@ -165,7 +166,11 @@ class SuperviseResult:
     reestimations: int = 0              # threshold epochs swapped in
     losses: list = field(default_factory=list)          # reference loss/step
     cand_losses: list = field(default_factory=list)
+    # thresholds_s (the threshold estimate) and steady_steps_per_s (steps
+    # after the first two, which carry the compiles), from the spans
     timings: dict = field(default_factory=dict)
+    # the change in ``repro.obs.table()`` over run() / resume()
+    obs: dict = field(default_factory=dict)
     work_dir: Optional[str] = None
     # ---- fault tolerance ---------------------------------------------------
     resumed_from: Optional[int] = None  # journaled-resume entry step
@@ -302,7 +307,8 @@ class Supervisor:
     # ---- journal + watchdog plumbing ---------------------------------------
     def _j(self, etype: str, **fields) -> None:
         if self.journal is not None:
-            self.journal.append(etype, **fields)
+            with obs.span("supervise.journal"):
+                self.journal.append(etype, **fields)
 
     def _config_dict(self) -> dict:
         sc = self.scfg
@@ -346,18 +352,18 @@ class Supervisor:
     def _build(self):
         sc = self.scfg
         batch0 = self.batch_fn(0)
-        t0 = time.perf_counter()
         if self.candidate is None:
             self.candidate = CandidateStep.build(self.cfg, self.pcfg,
                                                  self.params0, self.opt,
                                                  batch0)
         eps = sc.eps if sc.eps is not None else self.candidate.eps
         self.eps = eps
-        ref_runner = make_model_runner(self.model, self.params0, self.opt,
-                                       self.opt.init(self.params0))
-        thr, _ = estimate_thresholds(ref_runner, batch0, eps, sc.margin,
-                                     sc.seed)
-        t_thr = time.perf_counter() - t0
+        with obs.span("supervise.thresholds") as thr_span:
+            ref_runner = make_model_runner(self.model, self.params0,
+                                           self.opt,
+                                           self.opt.init(self.params0))
+            thr, _ = estimate_thresholds(ref_runner, batch0, eps, sc.margin,
+                                         sc.seed)
         # margins start at the constant widening either way: until the first
         # live re-estimation lands, only the step-0 estimate exists and the
         # full batch-to-batch allowance is still needed
@@ -375,26 +381,25 @@ class Supervisor:
         def loss_call(p, b, ctx):
             return self.model.loss(p, b, ctx=ctx)[0]
 
-        t0 = time.perf_counter()
         ref_dev = self._ref_device()
-        self._ref_step = make_trace_step(loss_call, self.opt, self.params0,
-                                         batch0, device=ref_dev)
+        with obs.span("supervise.build_ref"):
+            self._ref_step = make_trace_step(loss_call, self.opt,
+                                             self.params0, batch0,
+                                             device=ref_dev)
         self._ref_state = (self.params0, self.opt.init(self.params0))
         self._cand_state = (self.candidate.params0,
                             self.candidate.opt_state0)
-        timings = {"thresholds_s": t_thr}
         if sc.reestimate_every:
-            self._estimator = make_pair_estimator(
-                loss_call, self.opt, self.params0, batch0, eps, sc.margin,
-                sc.seed, device=ref_dev)
             # compile (and discard) one estimate now: the first live epoch
             # would otherwise carry seconds of jit time INSIDE the steady
             # loop — the dominant share of the old reest_async2 overhead
-            t1 = time.perf_counter()
-            self._estimator(self._ref_state[0], self._ref_state[1], batch0)
-            timings["estimator_warmup_s"] = time.perf_counter() - t1
-        timings["build_s"] = time.perf_counter() - t0
-        return thr, timings
+            with obs.span("supervise.estimator_warmup"):
+                self._estimator = make_pair_estimator(
+                    loss_call, self.opt, self.params0, batch0, eps,
+                    sc.margin, sc.seed, device=ref_dev)
+                self._estimator(self._ref_state[0], self._ref_state[1],
+                                batch0)
+        return thr, {"thresholds_s": thr_span.seconds}
 
     # ---- periodic threshold re-estimation ----------------------------------
     def _reestimate(self, k: int, rp, rs, batch, res: SuperviseResult):
@@ -407,21 +412,20 @@ class Supervisor:
         union tracks the real noise level and the constant widening
         tightens to the re-estimated multipliers (steps before this keep
         SUPERVISED_KIND_MULT)."""
-        t0 = time.perf_counter()
-        resolve = self._estimator.submit(rp, rs, batch, step=k)
-        self.pipe.schedule_epoch(k, resolve,
-                                 kind_mult=REESTIMATED_KIND_MULT)
-        if not self.scfg.overlap:
-            self.pipe.settle_epochs(k)       # the lockstep path blocks here
+        with obs.span("supervise.reestimate"):
+            resolve = self._estimator.submit(rp, rs, batch, step=k)
+            self.pipe.schedule_epoch(k, resolve,
+                                     kind_mult=REESTIMATED_KIND_MULT)
+            if not self.scfg.overlap:
+                self.pipe.settle_epochs(k)   # the lockstep path blocks here
         res.reestimations += 1
-        res.timings["reestimate_s"] = (res.timings.get("reestimate_s", 0.0)
-                                       + time.perf_counter() - t0)
         self.log(f"  [supervise] step {k}: live-batch threshold estimate "
                  f"dispatched (epoch {res.reestimations})")
 
     # ---- main loop ---------------------------------------------------------
     def run(self) -> SuperviseResult:
         sc = self.scfg
+        before = obs.table()
         thr, timings = self._build()
         res = SuperviseResult(flagged=False, steps_run=0,
                               first_flagged_step=None, first_bad_step=None,
@@ -430,8 +434,10 @@ class Supervisor:
         if sc.journal:
             self.journal = Journal(journal_path(self.work_dir))
             self._j("start", **self._config_dict())
-        return self._run_loop(res, start=0, flagged_steps=[],
-                              entry=(self._ref_state, self._cand_state))
+        res = self._run_loop(res, start=0, flagged_steps=[],
+                             entry=(self._ref_state, self._cand_state))
+        res.obs = obs.since(before)
+        return res
 
     def resume(self) -> SuperviseResult:
         """Re-enter a killed supervised run from its journal + work dir.
@@ -457,6 +463,7 @@ class Supervisor:
             raise ValueError("refusing to resume with a drifted config "
                              "(verdicts would silently change): "
                              + "; ".join(mism))
+        before = obs.table()
         thr, timings = self._build()
         # durable checkpoints: on disk AND CRC-clean — a write torn by the
         # crash is discarded here, loudly
@@ -505,26 +512,31 @@ class Supervisor:
             self._j("resume", step=start, durable=list(self.keeper.steps))
         self.log(f"  [supervise] resuming at step {start} "
                  f"({len(res.checks)} journaled verdicts restored)")
-        return self._run_loop(res, start=start,
-                              flagged_steps=flagged_steps, entry=entry)
+        res = self._run_loop(res, start=start, flagged_steps=flagged_steps,
+                             entry=entry)
+        res.obs = obs.since(before)
+        return res
 
     def _save_ckpt(self, k: int, ref_state, cand_state) -> None:
-        try:
-            self.keeper.save(k, ref_state, cand_state)
-        except Exception as e:        # noqa: BLE001 — surfaced + retried
-            # an earlier enqueued save failed; the writer restarted, this
-            # save re-submits — degraded checkpoint coverage is loud
-            self.watchdog.event("loud", k, f"ckpt writer: {e}")
-            self.keeper.save(k, ref_state, cand_state)
+        with obs.span("supervise.ckpt_enqueue"):
+            try:
+                self.keeper.save(k, ref_state, cand_state)
+            except Exception as e:    # noqa: BLE001 — surfaced + retried
+                # an earlier enqueued save failed; the writer restarted,
+                # this save re-submits — degraded checkpoint coverage is
+                # loud
+                self.watchdog.event("loud", k, f"ckpt writer: {e}")
+                self.keeper.save(k, ref_state, cand_state)
 
     def _ring_put(self, k: int, ref_tr, cand_tr) -> None:
-        try:
-            self.ring.put(k, ref_tr, cand_tr)
-        except Exception as e:        # noqa: BLE001 — surfaced, not fatal
-            # the put itself landed in memory before the stored writer
-            # error surfaced; the worker restarts on the next eviction and
-            # only spill coverage (not training) degraded
-            self.watchdog.event("loud", k, f"spill writer: {e}")
+        with obs.span("supervise.ring_put"):
+            try:
+                self.ring.put(k, ref_tr, cand_tr)
+            except Exception as e:    # noqa: BLE001 — surfaced, not fatal
+                # the put itself landed in memory before the stored writer
+                # error surfaced; the worker restarts on the next eviction
+                # and only spill coverage (not training) degraded
+                self.watchdog.event("loud", k, f"spill writer: {e}")
 
     def _run_loop(self, res: SuperviseResult, start: int,
                   flagged_steps: list[int], entry) -> SuperviseResult:
@@ -546,8 +558,7 @@ class Supervisor:
         timings = res.timings
         (rp, rs), (cp, cs) = entry
         cand_step = self.candidate.step
-        t_loop = time.perf_counter()
-        t_warm = None          # set once compile-bearing first steps are done
+        steady_s = 0.0     # the steps after the compile-bearing first two
         k = start
         # a resumed run whose journaled history already flagged goes
         # straight to diagnosis (the original run stopped there too)
@@ -558,74 +569,83 @@ class Supervisor:
                 if k == start + 2:
                     for x in res.losses + res.cand_losses:
                         getattr(x, "block_until_ready", lambda: None)()
-                    t_warm = time.perf_counter()
-                if k % sc.ckpt_every == 0:
-                    self._save_ckpt(k, (rp, rs), (cp, cs))
-                batch = self.batch_fn(k)
-                if (sc.reestimate_every and k
-                        and k % sc.reestimate_every == 0):
-                    self._reestimate(k, rp, rs, batch, res)
-                # both steps dispatch back-to-back — no host barrier between
-                # them; with a spare device the reference runs on its own
-                # device set concurrently with the candidate, and the host
-                # blocks only where the pipeline consumes values
-                ref_tr, rp, rs = self._ref_step(rp, rs, batch)
-                cand_tr, cp, cs = cand_step(cp, cs, batch)
-                if self.fault is not None:
-                    cand_tr = self.fault.cand_trace(k, cand_tr)
-                res.losses.append(ref_tr.loss)
-                res.cand_losses.append(cand_tr.loss)
-                if (sc.check_every > 0 and sc.async_window > 0
-                        and k % sc.check_every == 0):
-                    # saturation probe feeds the degradation policy BEFORE
-                    # the cadence decision: a sick pipeline raises the
-                    # effective cadence (checking degrades to sampling)
-                    # instead of blocking the loop on every submit
-                    self.degrade.note(k, self.pipe.saturated)
-                checked = False
-                if (sc.check_every > 0
-                        and k % self.degrade.effective_check_every == 0):
-                    checked = True
-                    if sc.async_window == 0:
-                        done = [self.pipe.check_sync(k, ref_tr, cand_tr)]
+                with obs.span("supervise.step", step=k) as step_span:
+                    if k % sc.ckpt_every == 0:
+                        self._save_ckpt(k, (rp, rs), (cp, cs))
+                    with obs.span("supervise.batch"):
+                        batch = self.batch_fn(k)
+                    if (sc.reestimate_every and k
+                            and k % sc.reestimate_every == 0):
+                        self._reestimate(k, rp, rs, batch, res)
+                    # both steps dispatch back-to-back — no host barrier
+                    # between them; with a spare device the reference runs
+                    # on its own device set concurrently with the
+                    # candidate, and the host blocks only where the
+                    # pipeline consumes values
+                    with obs.span("supervise.ref_dispatch"):
+                        ref_tr, rp, rs = self._ref_step(rp, rs, batch)
+                    with obs.span("supervise.cand_dispatch"):
+                        cand_tr, cp, cs = cand_step(cp, cs, batch)
+                    if self.fault is not None:
+                        cand_tr = self.fault.cand_trace(k, cand_tr)
+                    res.losses.append(ref_tr.loss)
+                    res.cand_losses.append(cand_tr.loss)
+                    if (sc.check_every > 0 and sc.async_window > 0
+                            and k % sc.check_every == 0):
+                        # saturation probe feeds the degradation policy
+                        # BEFORE the cadence decision: a sick pipeline
+                        # raises the effective cadence (checking degrades
+                        # to sampling) instead of blocking the loop on
+                        # every submit
+                        self.degrade.note(k, self.pipe.saturated)
+                    checked = (sc.check_every > 0 and
+                               k % self.degrade.effective_check_every == 0)
+                    if not checked:
+                        mode = "poll"
                     else:
-                        done = self.pipe.submit(k, ref_tr, cand_tr)
-                else:
-                    done = self.pipe.poll()
-                self._j("step", step=k, checked=checked)
-                self._ring_put(k, ref_tr, cand_tr)
-                if (self._absorb(done, res, flagged_steps)
-                        and sc.stop_on_flag):
+                        mode = "sync" if sc.async_window == 0 else "submit"
+                    with obs.span("supervise.check", mode=mode):
+                        if mode == "sync":
+                            done = [self.pipe.check_sync(k, ref_tr,
+                                                         cand_tr)]
+                        elif mode == "submit":
+                            done = self.pipe.submit(k, ref_tr, cand_tr)
+                        else:
+                            done = self.pipe.poll()
+                    self._j("step", step=k, checked=checked)
+                    self._ring_put(k, ref_tr, cand_tr)
+                    stop = (self._absorb(done, res, flagged_steps)
+                            and sc.stop_on_flag)
+                if k >= start + 2:
+                    steady_s += step_span.seconds
+                if stop:
                     k += 1
                     break
             else:
                 k = sc.steps
-        self._absorb(self.pipe.drain(), res, flagged_steps)
-        try:
-            self.ring.flush()        # background spill writes land on disk
-        except Exception as e:        # noqa: BLE001 — coverage loss, loud
-            self.watchdog.event("loud", k, f"spill writer: {e}")
-        try:
-            self.keeper.flush()      # checkpoint writes are durable too
-        except Exception as e:        # noqa: BLE001 — coverage loss, loud
-            self.watchdog.event("loud", k, f"ckpt writer: {e}")
+        with obs.span("supervise.drain") as drain_span:
+            self._absorb(self.pipe.drain(), res, flagged_steps)
+        with obs.span("supervise.flush") as flush_span:
+            try:
+                self.ring.flush()    # background spill writes land on disk
+            except Exception as e:    # noqa: BLE001 — coverage loss, loud
+                self.watchdog.event("loud", k, f"spill writer: {e}")
+            try:
+                self.keeper.flush()  # checkpoint writes are durable too
+            except Exception as e:    # noqa: BLE001 — coverage loss, loud
+                self.watchdog.event("loud", k, f"ckpt writer: {e}")
         res.steps_run = k
         res.losses = [float(x) for x in res.losses]
         res.cand_losses = [float(x) for x in res.cand_losses]
         ran = max(res.steps_run - start, 0)
-        timings["loop_s"] = time.perf_counter() - t_loop
-        timings["steps_per_s"] = ran / max(timings["loop_s"], 1e-9)
-        if t_warm is not None and ran > 2:
-            # steady-state rate: first two steps carry jit compilation
-            steady_s = time.perf_counter() - t_warm
+        if ran > 2:
+            steady_s += drain_span.seconds + flush_span.seconds
             timings["steady_steps_per_s"] = (ran - 2) / max(steady_s, 1e-9)
 
         if flagged_steps:
             res.flagged = True
             res.first_flagged_step = min(flagged_steps)
-            t0 = time.perf_counter()
             self._diagnose(res)
-            timings["diagnose_s"] = time.perf_counter() - t0
         res.timings = timings
         res.checks_rescued = self.pipe.rescued
         res.checks_lost = self.pipe.lost
@@ -735,9 +755,11 @@ class Supervisor:
         except Exception as e:    # noqa: BLE001 — coverage loss, loud
             self.watchdog.event("loud", res.first_flagged_step or 0,
                                 f"ckpt writer: {e}")
-        res.bisection = bisect_first_bad(self.keeper.steps,
-                                         res.first_flagged_step,
-                                         self._params_diverged, self._replay)
+        with obs.span("supervise.bisect"):
+            res.bisection = bisect_first_bad(self.keeper.steps,
+                                             res.first_flagged_step,
+                                             self._params_diverged,
+                                             self._replay)
         res.first_bad_step = res.bisection.first_bad_step
         res.bad_check = res.bisection.check
         self.ring.pin(res.first_bad_step)
@@ -750,8 +772,9 @@ class Supervisor:
         # bad step), so rewrite-mode module isolation applies as in the
         # single-step workflow (paper §3 step 5)
         ((rp, rs), (cp, cs)), ref_tr = self._bad_entry
-        ref_runner = make_model_runner(self.model, rp, self.opt, rs)
-        cand_runner = self.candidate.make_runner(cp, cs)
-        res.localization = localize_with_rewrites(
-            ref_runner, cand_runner, self.batch_fn(res.first_bad_step),
-            ref_tr, self.pipe.thresholds_for(res.first_bad_step))
+        with obs.span("supervise.localize"):
+            ref_runner = make_model_runner(self.model, rp, self.opt, rs)
+            cand_runner = self.candidate.make_runner(cp, cs)
+            res.localization = localize_with_rewrites(
+                ref_runner, cand_runner, self.batch_fn(res.first_bad_step),
+                ref_tr, self.pipe.thresholds_for(res.first_bad_step))

@@ -42,6 +42,7 @@ from typing import Callable, Optional
 
 import numpy as np
 
+from repro import obs
 from repro.checkpoint.store import (ChecksumError, load_checkpoint_named,
                                     save_checkpoint)
 from repro.core.collector import _SECTION_FIELDS, Trace
@@ -121,6 +122,7 @@ class BackgroundWriter:
     def submit(self, fn: Callable[[], None]) -> None:
         self.ensure()
         self._queue.put(fn)
+        obs.high(f"writer.queue_depth.{self.name}", self._queue.qsize())
 
     def take_error(self) -> Optional[BaseException]:
         """Pop the stored writer error (None when healthy).  The caller
@@ -253,6 +255,8 @@ class TraceRing:
                 raise err
 
     def put(self, step: int, ref: Trace, cand: Trace) -> None:
+        obs.count("ring.puts")
+        obs.count("ring.trace_bytes", ref.nbytes + cand.nbytes)
         self._mem[step] = (ref, cand)
         self._evict()
         self._surface_writer_error()
@@ -378,8 +382,10 @@ class TraceRing:
             if err is not None:
                 raise err
         root = os.path.join(self.spill_dir, f"step_{step:06d}")
-        save_trace(os.path.join(root, "ref"), ref, step=step)
-        save_trace(os.path.join(root, "cand"), cand, step=step)
+        with obs.span("spill.write", step=step):
+            save_trace(os.path.join(root, "ref"), ref, step=step)
+            save_trace(os.path.join(root, "cand"), cand, step=step)
+        obs.count("spill.bytes", ref.nbytes + cand.nbytes)
         with self._lock:
             self._spilled[step] = root
             self.spill_count += 1

@@ -76,3 +76,14 @@ def test_single_pair_wrapper_compiles_for_v5e(one_chip):
     x = jax.ShapeDtypeStruct((5632, 2047), jnp.bfloat16, sharding=one_chip)
     compiled = K.sq_norms.lower(x, x, interpret=False).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_check_kernel_is_named_in_the_program(one_chip):
+    # the kernel's name reaches the compiled program, so a device trace
+    # names it (``relerr_kernel``) inside ``jit_relerr_packed``
+    n_el, nb = _packed_layout([3 * K.DEFAULT_BLOCK + 5])
+    flat = jax.ShapeDtypeStruct((n_el,), jnp.float32, sharding=one_chip)
+    meta = jax.ShapeDtypeStruct((nb,), jnp.int32, sharding=one_chip)
+    lowered = K.packed_sq_norms.lower(flat, flat, meta, meta, n_segments=1,
+                                      interpret=False)
+    assert "relerr_kernel" in lowered.as_text()

@@ -116,12 +116,12 @@ def test_packed_engine_groups_match_loop(monkeypatch, group_elems):
     from repro.core import relerr_engine
     if group_elems is not None:
         monkeypatch.setattr(relerr_engine, "PACK_GROUP_ELEMS", group_elems)
-    relerr_engine._packed_pairs.clear_cache()
+    relerr_engine.relerr_packed.clear_cache()
     pairs = _pairs([5, BLOCK, 3 * BLOCK + 1, 17, 2 * BLOCK], seed=11)
     la = [jnp.asarray(a) for a, _ in pairs]
     lb = [jnp.asarray(b) for _, b in pairs]
     got = section_sq_norms(la, lb, mode="packed")
-    relerr_engine._packed_pairs.clear_cache()
+    relerr_engine.relerr_packed.clear_cache()
     np.testing.assert_allclose(got, section_sq_norms(la, lb, mode="loop"),
                                rtol=1e-5)
 
