@@ -23,6 +23,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core.tap import TraceContext
 
 
@@ -214,8 +215,7 @@ class Trace:
 # Reference collector (single-device)
 # ---------------------------------------------------------------------------
 
-def tap_shapes(loss_callable, params, batch, rewrites=None
-               ) -> tuple[dict, list[str]]:
+def tap_shapes(loss_callable, params, batch) -> tuple[dict, list[str]]:
     """Pass 1: eval_shape the forward to enumerate tap names/shapes.
 
     Also returns the tap names in FORWARD ORDER (jax sorts dict pytrees, but
@@ -223,8 +223,7 @@ def tap_shapes(loss_callable, params, batch, rewrites=None
     order: list[str] = []
 
     def f(params):
-        ctx = TraceContext("rewrite" if rewrites else "collect",
-                           rewrites=rewrites or {})
+        ctx = TraceContext("collect")
         loss_callable(params, batch, ctx)
         order.clear()
         order.extend(ctx.fwd.keys())
@@ -244,14 +243,18 @@ def trace_train_step(model, params, batch, opt=None, opt_state=None,
     ``rewrites``: {tap_name: np/jnp array} — overwrite module inputs
     (localization mode / threshold estimation).
     """
-    def loss_call(p, b, ctx):
-        loss, _ = model.loss(p, b, ctx=ctx)
-        return loss
-
-    return trace_fn_step(loss_call, params, batch, opt=opt,
+    return trace_fn_step(model_loss_call(model), params, batch, opt=opt,
                          opt_state=opt_state, rewrites=rewrites,
                          collect_act_grads=collect_act_grads,
                          tap_filter=tap_filter, jit=jit)
+
+
+def model_loss_call(model):
+    """``loss_call(params, batch, ctx) -> loss`` of a zoo ``Model``."""
+    def loss_call(p, b, ctx):
+        loss, _ = model.loss(p, b, ctx=ctx)
+        return loss
+    return loss_call
 
 
 def _make_probes(shapes, tap_filter, collect_act_grads):
@@ -263,49 +266,83 @@ def _make_probes(shapes, tap_filter, collect_act_grads):
             and jnp.issubdtype(s.dtype, jnp.floating)}
 
 
+def make_trace_collector(loss_call, opt=None, collect_act_grads=True,
+                         tap_filter=None, jit=True):
+    """Build ``collect(params, batch, opt_state=None, rewrites=None) ->
+    (Trace, new_params, new_opt_state)``: one training iteration with full
+    trace collection.
+
+    Data reach the compiled program only as arguments — the batch, the
+    zero probes and the rewrites — so two batches of one shape run one
+    program, and a new process finds it in the persistent compile cache.
+    The set of rewritten taps picks the program: a plain run and a run
+    that rewrites ``embedding/output`` (the threshold estimate's two runs)
+    are two programs.  Tap discovery runs once per batch shape.
+    """
+    def threshold_trace(p, b, pr, rw):
+        obs.count("thresholds.programs")
+
+        def loss_fn(pp, prr):
+            ctx = TraceContext("rewrite" if rw else "collect", probes=prr,
+                               rewrites=rw)
+            return loss_call(pp, b, ctx), ctx.fwd
+        (loss, fwd), (pgrads, agrads) = jax.value_and_grad(
+            loss_fn, argnums=(0, 1), has_aux=True)(p, pr)
+        return loss, fwd, pgrads, agrads
+
+    trace_c = in_full_precision(jax.jit(threshold_trace) if jit
+                                else threshold_trace)
+    upd_c = None
+    if opt is not None:
+        upd_c = in_full_precision(jax.jit(opt.update) if jit else opt.update)
+    layouts = {}    # batch signature -> (tap shapes, forward order)
+
+    def collect(params, batch, opt_state=None, rewrites=None):
+        b = jax.tree.map(jnp.asarray, batch)
+        leaves, treedef = jax.tree.flatten(b)
+        key = (treedef, tuple((x.shape, x.dtype) for x in leaves))
+        if key not in layouts:
+            layouts[key] = tap_shapes(loss_call, params, b)
+        shapes, fwd_order = layouts[key]
+        # a rewrite of a tap the model lacks has no effect
+        rw = {k: jnp.asarray(v) for k, v in (rewrites or {}).items()
+              if k in shapes}
+        probes = _make_probes(shapes, tap_filter, collect_act_grads)
+        loss, fwd, pgrads, agrads = trace_c(params, b, probes, rw)
+        del rw, probes      # not held while the update runs
+
+        tr = Trace()
+        tr.loss = float(loss)
+        tr.activations = {k: fwd[k] for k in fwd_order}
+        tr.act_grads = {k: agrads[k] for k in fwd_order if k in agrads}
+        tr.param_grads = flatten_named(pgrads)
+        tr.meta["fwd_order"] = list(fwd_order)
+
+        new_params, new_state = params, opt_state
+        if upd_c is not None:
+            new_params, new_state, info = upd_c(params, pgrads, opt_state)
+            tr.main_grads = flatten_named(info.main_grads)
+            tr.params_post = flatten_named(new_params)
+            tr.grad_norm = float(info.grad_norm)
+        return tr, new_params, new_state
+
+    return collect
+
+
 def trace_fn_step(loss_call, params, batch, opt=None, opt_state=None,
                   rewrites=None, collect_act_grads=True, tap_filter=None,
                   jit=True) -> tuple[Trace, dict, Optional[dict]]:
     """Generic collector over any ``loss_call(params, batch, ctx) -> loss``.
 
     Used for both the reference model and candidate step functions that
-    compute loss differently (e.g. pipeline-partitioned execution).
+    compute loss differently (e.g. pipeline-partitioned execution).  One
+    call of a ``make_trace_collector`` collector; a runner over many
+    batches builds the collector once instead.
     """
-    rewrites_j = (None if rewrites is None
-                  else {k: jnp.asarray(v) for k, v in rewrites.items()})
-    shapes, fwd_order = tap_shapes(loss_call, params, batch, rewrites_j)
-    mode = "rewrite" if rewrites_j else "collect"
-    probes = _make_probes(shapes, tap_filter, collect_act_grads)
-
-    def loss_fn(p, probes):
-        ctx = TraceContext(mode, probes=probes, rewrites=rewrites_j or {})
-        loss = loss_call(p, batch, ctx)
-        return loss, ctx.fwd
-
-    def threshold_trace(p, probes):
-        (loss, fwd), (pgrads, agrads) = jax.value_and_grad(
-            loss_fn, argnums=(0, 1), has_aux=True)(p, probes)
-        return loss, fwd, pgrads, agrads
-
-    step_c = in_full_precision(jax.jit(threshold_trace) if jit
-                               else threshold_trace)
-    loss, fwd, pgrads, agrads = step_c(params, probes)
-
-    tr = Trace()
-    tr.loss = float(loss)
-    tr.activations = {k: fwd[k] for k in fwd_order}
-    tr.act_grads = {k: agrads[k] for k in fwd_order if k in agrads}
-    tr.param_grads = flatten_named(pgrads)
-    tr.meta["fwd_order"] = list(fwd_order)
-
-    new_params, new_state = params, opt_state
-    if opt is not None:
-        upd = in_full_precision(jax.jit(opt.update) if jit else opt.update)
-        new_params, new_state, info = upd(params, pgrads, opt_state)
-        tr.main_grads = flatten_named(info.main_grads)
-        tr.params_post = flatten_named(new_params)
-        tr.grad_norm = float(info.grad_norm)
-    return tr, new_params, new_state
+    collect = make_trace_collector(loss_call, opt,
+                                   collect_act_grads=collect_act_grads,
+                                   tap_filter=tap_filter, jit=jit)
+    return collect(params, batch, opt_state, rewrites)
 
 
 # ---------------------------------------------------------------------------
@@ -337,7 +374,7 @@ def make_trace_step(loss_call, opt, params, batch,
     step is ``ref_step``; a candidate recipe built on this step passes
     ``cand_step``.
     """
-    shapes, fwd_order = tap_shapes(loss_call, params, batch, None)
+    shapes, fwd_order = tap_shapes(loss_call, params, batch)
     with device_ctx(device):
         probes = _make_probes(shapes, tap_filter, collect_act_grads)
 
@@ -385,11 +422,7 @@ def trace_pair_step(model, params, batch2, opt=None, opt_state=None,
     of threshold estimation: base and eps-perturbed reference run together
     instead of two serial jit round-trips.
     """
-    def loss_call(p, b, ctx):
-        loss, _ = model.loss(p, b, ctx=ctx)
-        return loss
-
-    return trace_fn_pair(loss_call, params, batch2, opt=opt,
+    return trace_fn_pair(model_loss_call(model), params, batch2, opt=opt,
                          opt_state=opt_state,
                          collect_act_grads=collect_act_grads,
                          tap_filter=tap_filter, jit=jit)
@@ -415,7 +448,7 @@ def make_pair_collector(loss_call, opt, params, batch, *,
     host floats convert).
     """
     batch_t = {k: jnp.asarray(v) for k, v in batch.items()}
-    shapes, fwd_order = tap_shapes(loss_call, params, batch_t, None)
+    shapes, fwd_order = tap_shapes(loss_call, params, batch_t)
     with device_ctx(device):
         probes = _make_probes(shapes, tap_filter, collect_act_grads)
 

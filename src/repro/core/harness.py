@@ -22,7 +22,8 @@ from dataclasses import dataclass
 from typing import Callable, Optional
 
 from repro.core.checker import Report, compare_traces, localize_with_rewrites
-from repro.core.collector import Trace, trace_pair_step, trace_train_step
+from repro.core.collector import (Trace, make_trace_collector, model_loss_call,
+                                  trace_pair_step)
 from repro.core.thresholds import MACHINE_EPS, Thresholds, estimate_thresholds
 
 
@@ -56,15 +57,20 @@ def make_model_runner(model, params, opt=None, opt_state=None,
                       tap_filter=None, jit=True) -> Callable:
     """Reference runner over the single-device model zoo.
 
+    The runner builds its trace program on its first call and reuses it for
+    every batch of that shape: one program for plain runs, one for each set
+    of rewritten taps (the threshold estimate's perturbed run).
+
     The returned runner also exposes ``run.pair(batch2) -> (Trace, Trace)``
     — two batches stacked on a leading axis collected in ONE vmapped
     compiled call — which threshold estimation uses to fuse the base and
     eps-perturbed reference runs for float-input models.
     """
+    collect = make_trace_collector(model_loss_call(model), opt,
+                                   tap_filter=tap_filter, jit=jit)
+
     def run(batch, rewrites=None) -> Trace:
-        tr, _, _ = trace_train_step(model, params, batch, opt=opt,
-                                    opt_state=opt_state, rewrites=rewrites,
-                                    tap_filter=tap_filter, jit=jit)
+        tr, _, _ = collect(params, batch, opt_state, rewrites)
         return tr
 
     def run_pair(batch2):

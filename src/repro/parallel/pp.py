@@ -29,7 +29,7 @@ import math
 import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig
-from repro.core.collector import Trace, make_trace_step, trace_fn_step
+from repro.core.collector import Trace, make_trace_collector, make_trace_step
 from repro.core.tap import ensure_ctx
 from repro.models.model import Model, block_apply
 
@@ -124,11 +124,10 @@ def make_pp_runner(model: Model, params, pp_size: int, opt=None,
     Tap names use canonical (global) layer indices reconstructed from
     (pp_rank, local index) — identical to the reference's names when the
     division is correct."""
-    loss_call = _pp_loss_call(model, pp_size, bugs)
+    collect = make_trace_collector(_pp_loss_call(model, pp_size, bugs), opt)
 
     def run(batch, rewrites=None) -> Trace:
-        tr, _, _ = trace_fn_step(loss_call, params, batch, opt=opt,
-                                 opt_state=opt_state, rewrites=rewrites)
+        tr, _, _ = collect(params, batch, opt_state, rewrites)
         return tr
 
     return run

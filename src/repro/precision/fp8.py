@@ -176,12 +176,12 @@ def fp8_precision(recipe: str, bugs=frozenset(),
 def make_fp8_runner(model, params, recipe: str, opt=None, opt_state=None,
                     bugs=frozenset(), use_kernel: bool = False):
     """Runner(batch, rewrites) -> Trace: the model with FP8 MLP matmuls."""
-    from repro.core.collector import trace_fn_step
-    loss_call = _fp8_loss_call(model, fp8_precision(recipe, bugs, use_kernel))
+    from repro.core.collector import make_trace_collector
+    collect = make_trace_collector(
+        _fp8_loss_call(model, fp8_precision(recipe, bugs, use_kernel)), opt)
 
     def run(batch, rewrites=None):
-        tr, _, _ = trace_fn_step(loss_call, params, batch, opt=opt,
-                                 opt_state=opt_state, rewrites=rewrites)
+        tr, _, _ = collect(params, batch, opt_state, rewrites)
         return tr
 
     return run
