@@ -32,17 +32,18 @@ import shutil
 import sys
 import tempfile
 import time
+import typing
 from functools import partial
 
 import numpy as np
 
 import checks
-import flops
 import load
 import trace_reduce
 from traffic import Batches
 
-STEP_PROGRAM = "_step"              # both sides' jitted training step
+# both sides' jitted training steps, by the program's names for them
+STEP_PROGRAMS = {"ref_step": "jit_ref_step", "cand_step": "jit_cand_step"}
 WARM_STEPS = 6                      # warm-up steps before the window
 
 
@@ -82,12 +83,17 @@ def seed_key(seed: int):
     return jax.random.wrap_key_data(state, impl="threefry2x32")
 
 
-def arch_config(name: str, c: dict):
-    """The program's ``ArchConfig`` for configuration ``c``."""
+def arch_config(name: str, c: dict, program: dict | None = None):
+    """The program's ``ArchConfig`` for configuration ``c`` (Hugging Face
+    key names), a dense decoder, with the configuration file's optional
+    ``program`` object on top: ``ArchConfig`` keyword arguments, a nested
+    object (``moe``, ``mla``, ``ssm``, ``hybrid``) built into its
+    dataclass by field name.  A key that ``ArchConfig`` or its nested
+    class lacks is refused."""
     from repro.configs.base import ArchConfig
     if c["rms_norm_eps"] != 1e-5 or c["torch_dtype"] != "float32":
         raise ValueError("the program runs float32 with RMSNorm eps 1e-5")
-    return ArchConfig(
+    kw = dict(
         name=name, arch_type="dense", n_layers=c["num_hidden_layers"],
         d_model=c["hidden_size"], n_heads=c["num_attention_heads"],
         n_kv_heads=c["num_key_value_heads"], d_ff=c["intermediate_size"],
@@ -96,25 +102,45 @@ def arch_config(name: str, c: dict):
         rope_theta=float(c["rope_theta"]),
         tie_embeddings=bool(c["tie_word_embeddings"]),
         param_dtype="float32", compute_dtype="float32", scan_layers=False)
+    kw.update(_fields_of(ArchConfig, program or {}, "program"))
+    return ArchConfig(**kw)
+
+
+def _fields_of(cls, vals: dict, where: str) -> dict:
+    """``vals`` as keyword arguments of dataclass ``cls``: an object
+    given for a field whose type is a dataclass is built into it."""
+    hints = typing.get_type_hints(cls)
+    names = {f.name for f in dataclasses.fields(cls)}
+    unknown = sorted(set(vals) - names)
+    if unknown:
+        raise ValueError(f"{where}: {cls.__name__} has no field "
+                         + ", ".join(unknown))
+    out = {}
+    for k, v in vals.items():
+        sub = [t for t in typing.get_args(hints[k]) or (hints[k],)
+               if dataclasses.is_dataclass(t)]
+        out[k] = (sub[0](**_fields_of(sub[0], v, f"{where}.{k}"))
+                  if sub and isinstance(v, dict) else v)
+    return out
 
 
 def nest(flat: dict) -> dict:
-    """Dotted names to the program's parameter tree (``layers`` is a
-    list)."""
+    """Dotted names to the program's parameter tree: a part that is a
+    whole number indexes a list (``layers.0.mlp.gate.w``)."""
     tree: dict = {}
     for name, x in flat.items():
         parts = name.split(".")
-        if parts[0] == "layers":
-            layers = tree.setdefault("layers", [])
-            i = int(parts[1])
-            while len(layers) <= i:
-                layers.append({})
-            node, parts = layers[i], parts[2:]
-        else:
-            node = tree
-        for p in parts[:-1]:
-            node = node.setdefault(p, {})
-        node[parts[-1]] = x
+        node = tree
+        for p, nxt in zip(parts, parts[1:] + [None]):
+            new = x if nxt is None else ([] if nxt.isdigit() else {})
+            if isinstance(node, list):
+                i = int(p)
+                node.extend([None] * (i + 1 - len(node)))
+                if node[i] is None:
+                    node[i] = new
+                node = node[i]
+            else:
+                node = node.setdefault(p, new)
     return tree
 
 
@@ -301,7 +327,8 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     clock = CompileClock()
     ref_mod = load.reference(cell["config"]["reference"])
-    cfg = arch_config(cell["config"]["name"], c)
+    cfg = arch_config(cell["config"]["name"], c,
+                      cell["config"].get("program"))
     model = Model(cfg)
     key = seed_key(seed)
     params0 = jax.jit(lambda k: nest(ref_mod.init_weights(k, c)))(key)
@@ -428,7 +455,7 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
             red = trace_reduce.load(path, "bench.window")
             ctx = {"trace": red, "steps": max(steps, 1),
                    "window_s": window_s,
-                   "flops_per_step": 2 * flops.train_flops(
+                   "flops_per_step": 2 * ref_mod.train_flops(
                        c, tf["batch"] * tf["dp"], tf["seq"]),
                    "peaks": peaks, "compile_s": mark["compile_s"],
                    "thresholds_s": thresholds_s,
@@ -438,6 +465,11 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
                 if v is not None:
                     out["metrics"][m["name"]] = {"value": v,
                                                  "unit": units[m["name"]]}
+            for role, prog in STEP_PROGRAMS.items():
+                split = red.scope_split(prog)
+                log(f"{prog}: device {ctx['programs'].get(role)!r} s, by "
+                    "scope " + ", ".join(f"{k} {v!r}" for k, v in sorted(
+                        split.items(), key=lambda kv: -kv[1])))
             out["device"].update(busy_s=red.busy_s(), window_s=red.window_s)
             out["breakdown"] = {"device_ops": red.top_ops(10),
                                 "idle_gaps": red.top_gaps(10)}
@@ -459,24 +491,16 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
 
 def programs(red) -> dict:
     """Device seconds by program role, averaged over the devices that ran
-    it: ``ref_step`` and ``cand_step``.
-
-    Both sides' training steps compile a function named ``_step``; the
-    trace names each program with its fingerprint as well.  A device runs
-    its programs in the order they were dispatched and every step
-    dispatches the reference before the candidate, so on the device that
-    runs the reference (the last) the first ``_step`` program of the trace
-    is the reference's and any other is the candidate's."""
-    ref_dev = red.devices[-1] if red.devices else None
-    ref_name = next((n for n in red.first_seen.get(ref_dev, [])
-                     if STEP_PROGRAM in n), None)
+    it: ``ref_step`` and ``cand_step``, found by the program's names for
+    the two steps (``STEP_PROGRAMS``; the trace adds a fingerprint:
+    ``jit_ref_step(1234)``)."""
     per_role: dict = {}
     for dev in red.devices:
         tot: dict = {}
         for e in red.modules[dev]:
-            if STEP_PROGRAM in e.name:
-                role = "ref_step" if e.name == ref_name else "cand_step"
-                tot[role] = tot.get(role, 0.0) + e.dur
+            for role, prog in STEP_PROGRAMS.items():
+                if e.name.split("(", 1)[0] == prog:
+                    tot[role] = tot.get(role, 0.0) + e.dur
         for role, sec in tot.items():
             per_role.setdefault(role, []).append(sec)
     return {r: sum(v) / len(v) for r, v in per_role.items()}

@@ -6,6 +6,13 @@ every weight matrix for every token, the causal half of the attention
 score and value products, and three times the forward for a training
 step.  Recomputation does not count.  The embedding lookup is a gather
 and counts nothing; the tied output head counts as a matrix.
+
+Each configuration's reference module gives the count for its
+architecture (``train_flops(c, batch, seq)``); the functions here count a
+dense block.  A sparse (mixture-of-experts) layer counts its router and
+shared experts for every token, and its routed experts for the tokens
+routed to them: tokens times experts per token, each through one
+expert's matrices, not the padded capacity the program computes.
 """
 from __future__ import annotations
 

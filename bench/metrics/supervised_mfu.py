@@ -1,7 +1,7 @@
-"""Model FLOPs of both sides' training steps (``flops.train_flops``) per
-second of the traced window, over the chips' bf16 peak, in %.  Float32 at
-``highest`` precision takes about six bf16 passes, so about 17% is its
-ceiling."""
+"""Model FLOPs of both sides' training steps (the configuration's
+reference module's ``train_flops``) per second of the traced window, over
+the chips' bf16 peak, in %.  Float32 at ``highest`` precision takes about
+six bf16 passes, so about 17% is its ceiling."""
 
 
 def read(ctx):

@@ -17,7 +17,8 @@ with two departures, stated in each configuration file: RMSNorm epsilon
 the system under test computes; neither changes a shape or a FLOP.
 
 ``c`` is the configuration file's ``config`` object (Hugging Face key
-names).
+names).  ``train_flops`` gives the FLOPs that ``supervised_mfu`` counts
+for one training step of one side.
 """
 from __future__ import annotations
 
@@ -26,6 +27,8 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+import flops
 
 EPS = 1e-5
 
@@ -52,6 +55,13 @@ def leaf_shapes(c: dict) -> dict:
         shapes[p + "mlp.up.w"] = (d, f)
         shapes[p + "mlp.down.w"] = (f, d)
     return shapes
+
+
+def train_flops(c: dict, batch: int, seq: int) -> int:
+    """FLOPs of one training step of one side on ``batch`` sequences of
+    ``seq`` tokens: dense blocks and the tied output head
+    (``flops.train_flops``)."""
+    return flops.train_flops(c, batch, seq)
 
 
 def init_weights(key, c: dict) -> dict:

@@ -3,6 +3,8 @@ import json
 import os
 import sys
 
+import pytest
+
 BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, BENCH)
 
@@ -47,3 +49,13 @@ def test_parameter_counts_of_the_reference():
                     for s in ref.leaf_shapes(c).values())
         assert total == flops.matmul_params(c) + norms
 
+
+@pytest.mark.parametrize("name", ["qwen3-1.7b", "qwen2-1.5b"])
+@pytest.mark.parametrize("batch,seq", [(1, 2048), (2, 2048), (4, 512)])
+def test_reference_counts_as_flops_does(name, batch, seq):
+    """``supervised_mfu`` takes the count from the configuration's
+    reference; for the dense reference it is ``flops.train_flops`` to the
+    FLOP."""
+    c = config(name)
+    ref = load.reference("qwen_dense")
+    assert ref.train_flops(c, batch, seq) == flops.train_flops(c, batch, seq)

@@ -45,6 +45,7 @@ def test_config_file_states_its_cuts(cfg):
         assert body["config"][k] != body["reduced"][k]
     ref = load.reference(body["reference"])
     assert callable(ref.loss) and callable(ref.train_step)
+    assert callable(ref.train_flops)
 
 
 @pytest.mark.parametrize("w", BJ["workloads"], ids=lambda w: w["name"])

@@ -113,7 +113,6 @@ def test_programs_gives_the_named_steps_their_roles():
     red.modules[DEV] = [Event(ref, 0.0, 0.05), Event(cand, 0.05, 0.11),
                         Event("jit_relerr_fused(7)", 0.11, 0.12),
                         Event(ref, 0.12, 0.17), Event(cand, 0.17, 0.23)]
-    red.first_seen[DEV] = [ref, cand, "jit_relerr_fused(7)"]
     got = cell.programs(red)
     assert got == {"ref_step": pytest.approx(0.10),
                    "cand_step": pytest.approx(0.12)}
